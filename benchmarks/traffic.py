@@ -43,12 +43,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np
 from repro.core import graph as G, partition as PT, algorithms as ALG
 from repro.core.engine_shardmap import ShardEngine
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 
 SCALE, EDGE_FACTOR, P = %(scale)d, %(edge_factor)d, 4
 g = G.rmat(SCALE, EDGE_FACTOR, seed=7)
 pg = PT.partition_graph(g, P, method="greedy", pad_multiple=16)
-mesh = compat_make_mesh((P,), ("graph",))
+mesh = auto_mesh((P,), ("graph",))
 
 out = {"num_vertices": g.num_vertices, "num_edges": g.num_edges, "P": P}
 state = {}
@@ -84,7 +84,9 @@ def traffic():
     scale, edge_factor = (10, 128)
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     script = _SCRIPT % {"scale": scale, "edge_factor": edge_factor}
-    env = dict(os.environ,
+    # the child runs on forced host-platform devices and must never
+    # contend with this process for an accelerator
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.path.abspath(src)
                + os.pathsep + os.environ.get("PYTHONPATH", ""))
     proc = subprocess.run([sys.executable, "-c", script],

@@ -37,6 +37,7 @@ import numpy as np
 
 from ..kernels import ops as kops
 from ..kernels import ref as kref
+from ..kernels.edge_gather import segment_combine_windows
 from .gas import GasKernel
 from .partition import PartitionedGraph
 from .stepper import LaneStepper, SuperstepProgram
@@ -58,6 +59,10 @@ class _GravfmData(NamedTuple):
     lane_valid: jnp.ndarray     # (L,) bool
     lane_remote: jnp.ndarray    # (L,) bool: src shard != dst shard
     seg: jnp.ndarray            # (L,) int32 clipped segment ids (carry path)
+    # Pallas tile layout (backend="pallas"; None for "ref")
+    wid: Optional[jnp.ndarray] = None      # (n_tiles,) int32
+    rel: Optional[jnp.ndarray] = None      # (L,) int32
+    written: Optional[jnp.ndarray] = None  # (n_windows,) bool
 
 
 class _GravfData(NamedTuple):
@@ -196,6 +201,10 @@ class Engine:
             lane_valid=jnp.asarray(lane_valid),
             lane_remote=jnp.asarray(lane_remote),
             seg=jnp.asarray(np.minimum(seg, S).astype(np.int32)),
+            **({} if self._layout is None else dict(
+                wid=jnp.asarray(self._layout.window_id),
+                rel=jnp.asarray(self._layout.rel),
+                written=jnp.asarray(self._layout.window_written))),
         )
 
     def _build_gravf(self, flt_cnt) -> _GravfData:
@@ -214,6 +223,18 @@ class Engine:
         )
 
     # ------------------------------------------------------------------
+    def _combine(self, data: _GravfmData, vals, combiner: str):  # analysis: traced
+        """Segmented combine over the CSC lanes: the Pallas kernel over
+        the tile layout carried in ``data``, or the jnp oracle."""
+        if self.backend == "pallas":
+            lo = self._layout
+            return segment_combine_windows(
+                data.wid, data.rel, vals, combiner=combiner,
+                tile_e=lo.tile_e, tile_r=lo.tile_r, n_windows=lo.n_windows,
+                window_written=data.written, num_segments=lo.num_segments)
+        return kref.segment_combine(vals, data.seg, self._num_segments,
+                                    combiner)
+
     def _deliver_gravfm(self, data: _GravfmData, payload, active):  # analysis: traced
         """Broadcast updates; receiver-side scatter + gather-combine."""
         k, P, Vm = self.kernel, self._P, self._Vm
@@ -227,24 +248,14 @@ class Engine:
         ident = kops.identity_for(k.combiner, k.msg_dtype)
         masked = jnp.where(act, msg, ident)
 
-        if self.backend == "pallas":
-            acc_full = kops.segment_combine_layout(
-                masked, self._layout, k.combiner)
-        else:
-            acc_full = kref.segment_combine(
-                masked, data.seg, self._num_segments, k.combiner)
+        acc_full = self._combine(data, masked, k.combiner)
         acc = acc_full.reshape(P, Vm + 1)[:, :Vm]
 
         if k.got_from_identity:
             got = acc != ident
         else:
             gv = jnp.where(act, 1, 0).astype(jnp.int32)
-            if self.backend == "pallas":
-                got_full = kops.segment_combine_layout(
-                    gv, self._layout, "max")
-            else:
-                got_full = kref.segment_combine(
-                    gv, data.seg, self._num_segments, "max")
+            got_full = self._combine(data, gv, "max")
             got = got_full.reshape(P, Vm + 1)[:, :Vm] > 0
 
         carry = None
@@ -256,12 +267,7 @@ class Engine:
                 data.seg, self._num_segments - 1))
             winner = act & (masked == acc_at_lane)
             cmasked = jnp.where(winner, cvals, cident)
-            if self.backend == "pallas":
-                carry_full = kops.segment_combine_layout(
-                    cmasked, self._layout, "min")
-            else:
-                carry_full = kref.segment_combine(
-                    cmasked, data.seg, self._num_segments, "min")
+            carry_full = self._combine(data, cmasked, "min")
             carry = carry_full.reshape(P, Vm + 1)[:, :Vm]
 
         n_msgs = jnp.sum(act.astype(jnp.int32))
@@ -492,6 +498,17 @@ class Engine:
                 raw_state=state_q,
             ))
         return results
+
+    def lower_batch(self, batch: int) -> jax.stages.Lowered:
+        """Lower the program :meth:`run_batch` dispatches for ``batch``
+        queries against this engine's graph arrays, without running it.
+        ``.compile()`` on the result gives the compiler's verdict for the
+        device: ``memory_analysis()`` says whether the batch fits, and
+        ``as_text()`` shows which kernels it holds."""
+        qkw = {p: jax.ShapeDtypeStruct((batch,), jnp.int32)
+               for p in self.kernel.query_params}
+        cap = self.kernel.max_supersteps or HARD_SUPERSTEP_CAP
+        return self._batch_step.lower(self._data, jnp.int32(cap), qkw)
 
     # ------------------------------------------------------------------
     def make_stepper(self, width: int) -> LaneStepper:

@@ -96,18 +96,10 @@ __all__ = ["ShardEngine", "ShardLaneStepper", "build_shard_data",
 
 AXIS = "graph"
 
-if hasattr(jax, "shard_map"):          # jax >= 0.6 public API
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        # version-compat shim, invoked only from _build-time factories
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,  # analysis: allow(RTR002)
-                             out_specs=out_specs, check_vma=False)
-else:                                  # 0.4.x experimental API
-    from jax.experimental.shard_map import shard_map as _sm_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        # version-compat shim, invoked only from _build-time factories
-        return _sm_legacy(f, mesh=mesh, in_specs=in_specs,  # analysis: allow(RTR002)
-                          out_specs=out_specs, check_rep=False)
+def _shard_map(f, *, mesh, in_specs, out_specs):
+    # invoked only from _build-time factories
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,  # analysis: allow(RTR002)
+                         out_specs=out_specs, check_vma=False)
 
 
 class ShardData(NamedTuple):
@@ -408,7 +400,6 @@ class ShardEngine:
             self._data = None
         self._device_resident = self._data is not None
         self.params.setdefault("num_vertices", self.meta.num_vertices)
-        self._interpret = jax.default_backend() != "tpu"
         # jitted program cache (per superstep cap) + trace counter; see
         # Engine.traces for the counting trick.
         self.traces = 0
@@ -476,7 +467,7 @@ class ShardEngine:
                 d.wid, d.rel, masked, combiner=combiner,
                 tile_e=m.tile_e, tile_r=m.tile_r, n_windows=m.n_windows,
                 window_written=d.window_written,
-                num_segments=m.v_max + 1, interpret=self._interpret)
+                num_segments=m.v_max + 1)
         return kref.segment_combine(masked, d.seg, m.v_max + 1, combiner)
 
     def _comb_combine(self, masked, d, combiner):  # analysis: traced
@@ -490,7 +481,7 @@ class ShardEngine:
                 d.comb_wid, d.comb_rel, masked, combiner=combiner,
                 tile_e=m.tile_e, tile_r=m.tile_r,
                 n_windows=m.comb_windows, window_written=d.comb_written,
-                num_segments=n_seg, interpret=self._interpret)
+                num_segments=n_seg)
         return kref.segment_combine(masked, d.comb_seg, n_seg, combiner)
 
     def _consume(self, d, payload_flat, active_flat):  # analysis: traced

@@ -33,6 +33,7 @@ from typing import Dict, Optional
 __all__ = [
     "Platform", "AlgoProfile", "Workload", "limits", "speedup_eq5",
     "optimize", "PAPER_PLATFORM", "TPU_V5E", "PAPER_ALGOS", "tpu_algo",
+    "PLATFORMS_BY_DEVICE_KIND",
     "words_per_superstep", "traffic_reduction", "EXCHANGES",
     "PHASE_TERMS", "phase_projection", "overlapped_limits",
     "overlapped_projection",
@@ -111,6 +112,13 @@ TPU_V5E = Platform(
     m_memword=512,               # VMEM tile granularity (§5.4 analogue)
     n_nodes_max=512,
 )
+
+# Platforms by the ``device_kind`` JAX reports for the chip. A kind that
+# is not here (CPU included) has no projected roofline: a caller that
+# wants one passes a Platform explicitly.
+PLATFORMS_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+}
 
 
 def tpu_algo(name: str, *, tile_r: int = 256, ops_per_pair: float = 4.0,

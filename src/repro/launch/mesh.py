@@ -16,36 +16,32 @@ import jax
 from jax.sharding import Mesh
 
 __all__ = ["make_production_mesh", "make_graph_mesh", "make_local_mesh",
-           "make_serving_mesh", "compat_make_mesh"]
+           "make_serving_mesh", "auto_mesh"]
 
 
-def compat_make_mesh(shape, axes) -> Mesh:
-    """jax.make_mesh across versions: newer jax wants explicit Auto
-    axis_types; 0.4.x has no AxisType (Auto is the only behaviour)."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
+def auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto`` (XLA SPMD picks the
+    collectives)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_graph_mesh(*, multi_pod: bool = False) -> Mesh:
     """All chips on one 'graph' axis for the GraVF-M engine."""
     n = 512 if multi_pod else 256
-    return compat_make_mesh((n,), ("graph",))
+    return auto_mesh((n,), ("graph",))
 
 
 def make_local_mesh(axes=("graph",)) -> Mesh:
     """Whatever devices exist locally (tests / reduced runs)."""
     n = len(jax.devices())
-    return compat_make_mesh((n,), axes)
+    return auto_mesh((n,), axes)
 
 
 def make_serving_mesh(num_shards: int) -> Mesh:
@@ -65,4 +61,4 @@ def make_serving_mesh(num_shards: int) -> Mesh:
             f"--xla_force_host_platform_device_count={num_shards} "
             "before importing jax (or run on a platform with enough "
             "devices)")
-    return compat_make_mesh((num_shards,), ("graph",))
+    return auto_mesh((num_shards,), ("graph",))

@@ -245,8 +245,13 @@ class GraphQueryService:
         self._class_meta: Dict[str, QueryClass] = {}
         self._limits_cache: \
             Dict[str, Optional[Dict[str, float]]] = {}
-        self._roofline_platform = (roofline_platform or platform
-                                   or perfmodel.PAPER_PLATFORM)
+        # the model platform of the device this service runs on; a
+        # device kind the model does not know (CPU included) gets no
+        # projected roofline unless a platform is passed explicitly
+        self._roofline_platform = (
+            roofline_platform or platform
+            or perfmodel.PLATFORMS_BY_DEVICE_KIND.get(
+                jax.devices()[0].device_kind))
         self.stats.set_roofline_projector(self._project_teps)
         self._lock = threading.RLock()  # lock: server
         self._wake = threading.Condition(self._lock)  # lock: server
@@ -627,7 +632,7 @@ class GraphQueryService:
             return self._limits_cache[ck]
         qclass = self._class_meta.get(ck)
         lim: Optional[Dict[str, float]] = None
-        if qclass is not None:
+        if qclass is not None and self._roofline_platform is not None:
             try:
                 g = self.store.host_graph(qclass.graph_id,
                                           qclass.version or None)

@@ -36,8 +36,8 @@ def test_data_process_sharding():
 
 
 def test_logical_to_spec_divisibility():
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((1,), ("model",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("model",))
     # 'model' size 1: everything maps but is trivial; use the table only.
     spec = SH.logical_to_spec(mesh, ("batch", None, "vocab"), (8, 4, 100))
     assert isinstance(spec, P)

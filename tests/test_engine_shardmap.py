@@ -20,8 +20,8 @@ from repro.core import graph as G, partition as PT, algorithms as ALG
 from repro.core.engine import Engine
 from repro.core.engine_shardmap import ShardEngine
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("graph",))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((8,), ("graph",))
 g = G.uniform(300, 6.0, seed=3).symmetrized()
 pg = PT.partition_graph(g, 8, method="greedy", pad_multiple=16)
 
@@ -149,9 +149,9 @@ import numpy as np
 from repro.core import graph as G, partition as PT, algorithms as ALG
 from repro.core.engine import Engine
 from repro.core.engine_shardmap import ShardEngine
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 
-mesh = compat_make_mesh((8,), ("graph",))
+mesh = auto_mesh((8,), ("graph",))
 # weighted so SSSP exercises the lexicographic (dist, parent) carry
 # through the windowed pipeline's per-window merge
 gw = G.uniform(300, 6.0, seed=3, weighted=True).symmetrized()

@@ -23,8 +23,7 @@ def _run_both(seg, vals, num_segments, combiner, tile_e, tile_r):
     ident = kops.identity_for(combiner, vals_padded.dtype)
     vp = jnp.where(jnp.asarray(layout.lane_valid), jnp.asarray(vals_padded),
                    ident)
-    out_k = kops.segment_combine_layout(vp, layout, combiner,
-                                        interpret=True)
+    out_k = kops.segment_combine_layout(vp, layout, combiner)
     out_r = kref.segment_combine(jnp.asarray(vals),
                                  jnp.asarray(seg.astype(np.int32)),
                                  num_segments, combiner)
@@ -58,6 +57,36 @@ def test_kernel_vs_ref_sweep(combiner, dtype, n_edges, n_segments,
         np.testing.assert_allclose(out_k, out_r, rtol=1e-5, atol=1e-5)
     else:
         np.testing.assert_array_equal(out_k, out_r)
+
+
+@pytest.mark.parametrize("nested", [False, True])
+@pytest.mark.parametrize("combiner", ["min", "max", "add"])
+def test_kernel_vmapped_rows_match_per_row(combiner, nested):
+    """vmap over query rows (how every served path calls the kernel) folds
+    the batch into the kernel's row axis; each row equals its own call."""
+    import jax
+    rng = np.random.default_rng(5)
+    n_edges, n_seg = 900, 300
+    seg = _random_sorted_segments(rng, n_edges, n_seg)
+    layout = build_layout(seg, n_seg, tile_e=128, tile_r=64)
+    rows = rng.integers(-100, 100, size=(6, n_edges)).astype(np.int32)
+    ident = kops.identity_for(combiner, rows.dtype)
+    padded = np.stack([np.where(layout.lane_valid, layout.place(r, 0), ident)
+                       for r in rows]).astype(np.int32)
+
+    def one(v):
+        return kops.segment_combine_layout(v, layout, combiner)
+
+    if nested:
+        out = jax.vmap(jax.vmap(one))(jnp.asarray(padded.reshape(2, 3, -1)))
+        out = np.asarray(out).reshape(6, n_seg)
+    else:
+        out = np.asarray(jax.vmap(one)(jnp.asarray(padded)))
+    for r, got in zip(rows, out):
+        want = kref.segment_combine(jnp.asarray(r),
+                                    jnp.asarray(seg.astype(np.int32)),
+                                    n_seg, combiner)
+        np.testing.assert_array_equal(got, np.asarray(want))
 
 
 @settings(max_examples=30, deadline=None)

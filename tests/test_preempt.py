@@ -397,9 +397,9 @@ import jax, numpy as np
 from repro.core import graph as G, partition as PT, algorithms as ALG
 from repro.core.engine import Engine
 from repro.core.engine_shardmap import ShardEngine
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 
-mesh = compat_make_mesh((4,), ("graph",))
+mesh = auto_mesh((4,), ("graph",))
 g = G.uniform(200, 5.0, seed=3).symmetrized()
 pg = PT.partition_graph(g, 4, method="greedy", pad_multiple=16)
 

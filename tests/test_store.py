@@ -868,10 +868,10 @@ import numpy as np
 from repro.core import graph as G, partition as PT, algorithms as ALG
 from repro.core.engine import Engine
 from repro.core.engine_shardmap import ShardEngine
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.store import GraphStore
 
-mesh = compat_make_mesh((8,), ("graph",))
+mesh = auto_mesh((8,), ("graph",))
 deep = G.ladder(2, 30, 1, seed=0)
 other = G.uniform(300, 6.0, seed=2).symmetrized()
 budget = 1.2 * PT.partition_graph(deep, 8, pad_multiple=16).device_nbytes

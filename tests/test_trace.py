@@ -30,6 +30,8 @@ def small_graph():
 def _service(small_graph, **kw):
     kw.setdefault("num_shards", 2)
     kw.setdefault("max_batch", 8)
+    # CPU has no model platform of its own: project against the paper's
+    kw.setdefault("roofline_platform", perfmodel.PAPER_PLATFORM)
     svc = GraphQueryService(**kw)
     svc.add_graph("g", small_graph)
     return svc
