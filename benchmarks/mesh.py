@@ -15,17 +15,12 @@ exchange schedule end to end on the combined-exchange R-MAT workload:
     ``overlap`` per run — re-trace nothing once both schedules are warm;
   * **throughput**: steady-state TEPS under the overlapped schedule vs
     synchronous on the same engine (the act-stream elision plus the
-    window pipeline must actually pay, not just not regress);
-  * **roofline**: the §6 methodology applied to the overlap claim —
-    profile the synchronous schedule's phase split (exchange wall E,
-    local-compute wall A), project the overlapped superstep floor
-    ``max(E, A)`` via :func:`perfmodel.overlapped_projection`, and
-    compare the measured overlapped superstep wall against it.
+    window pipeline must actually pay, not just not regress), and the
+    step-granular steppers' superstep wall under each schedule.
 
 ``GRAVFM_BENCH_CI=1`` turns the comparisons into gates:
     bit-identical results, zero steady-state re-traces
     overlapped TEPS >= 1.15x synchronous (combined-exchange R-MAT BFS)
-    measured/projected overlapped-pipeline efficiency >= 0.7
 
 The run always writes ``bench-mesh.json`` (or ``$GRAVFM_MESH_OUT``);
 the CI workflow uploads it and appends the ``BENCH_mesh.json``
@@ -103,20 +98,13 @@ for ov in (False, True):
     out["teps_%%s" %% ("ov" if ov else "sync")] = msgs / wall
 out["teps_ratio"] = teps["ov"] / teps["sync"]
 
-# ---- roofline: profiled sync phase split -> overlapped projection ----
-# Drive the step-granular steppers over the same alive schedule: the
-# profiled synchronous stepper yields the exchange wall E and the
-# local-compute wall A per superstep; perfmodel.overlapped_projection
-# says the pipelined superstep floor is max(E, A); the measured
-# overlapped stepper wall is compared against that floor (§6 applied
-# to the overlap claim).
+# ---- step-granular steppers: superstep wall, sync vs overlapped ------
 roots = {"root": jnp.full((W,), np.int32(root))}
 st_sync = eng.make_stepper(W, overlap=False)
 st_ov = eng.make_stepper(W, overlap=True)
 
-def drive(st, profile, reps=3):
-    st.profile = profile
-    walls, phases = [], []
+def drive(st, reps=3):
+    walls = []
     for _ in range(reps):
         carry, act, steps = st.init(roots)
         alive = np.asarray(act)
@@ -124,23 +112,15 @@ def drive(st, profile, reps=3):
         n = 0
         while alive.any():
             carry, act, steps = st.step(carry, alive)
-            if profile and getattr(st, "last_phases", None):
-                phases.append(dict(st.last_phases))
             alive = np.asarray(act)
             n += 1
         walls.append((time.perf_counter() - t0, n))
     wall, n = min(walls)                 # best-of over jitter
-    return wall / n, n, phases
+    return wall / n, n
 
-per_step_sync_prof, depth, phases = drive(st_sync, True)
-E = float(np.median([p["exchange"] for p in phases]))
-A = float(np.median([p.get("scatter", 0.0) + p.get("combine", 0.0)
-                     + p.get("apply", 0.0) for p in phases]))
-per_step_ov, _, _ = drive(st_ov, False)
-per_step_sync, _, _ = drive(st_sync, False)
+per_step_ov, depth = drive(st_ov)
+per_step_sync, _ = drive(st_sync)
 out["depth"] = depth
-out["phase_exchange_s"] = E
-out["phase_compute_s"] = A
 out["superstep_sync_s"] = per_step_sync
 out["superstep_ov_s"] = per_step_ov
 print("MESH-JSON:" + json.dumps(out))
@@ -169,12 +149,7 @@ def mesh():
     meas = json.loads(line[len("MESH-JSON:"):])
 
     from repro.core import perfmodel as pm
-    # projected overlapped superstep floor from the measured sync phase
-    # split (time domain), plus the rate-domain model gain for context
-    proj = pm.overlapped_projection(meas["phase_compute_s"],
-                                    meas["phase_exchange_s"])
-    overlap_eff = (proj["overlapped_s"] / meas["superstep_ov_s"]
-                   if meas["superstep_ov_s"] > 0 else 0.0)
+    # the rate-domain model gain of overlapping, for context
     wl = pm.Workload(meas["num_vertices"], meas["num_edges"])
     lim = pm.limits(pm.PAPER_PLATFORM, pm.PAPER_ALGOS["bfs"], wl,
                     n_nodes=meas["P"], exchange="combined")
@@ -191,20 +166,17 @@ def mesh():
             meas["identical"], retraced))
     emit("mesh/rmat%d_ef%d/overlap" % (scale, edge_factor),
          meas["superstep_sync_s"] * 1e6,
-         "E=%.0fus;A=%.0fus;proj=%.0fus;meas_ov=%.0fus;eff=%.2f;"
-         "model_gain=%.2fx"
-         % (meas["phase_exchange_s"] * 1e6, meas["phase_compute_s"] * 1e6,
-            proj["overlapped_s"] * 1e6, meas["superstep_ov_s"] * 1e6,
-            overlap_eff, model["overlap_gain"]))
+         "sync=%.0fus;ov=%.0fus;model_gain=%.2fx"
+         % (meas["superstep_sync_s"] * 1e6, meas["superstep_ov_s"] * 1e6,
+            model["overlap_gain"]))
 
     out_path = os.environ.get("GRAVFM_MESH_OUT", "bench-mesh.json")
     with open(out_path, "w") as f:
         json.dump({"measured": meas,
-                   "projected": {**proj, "model_overlap_gain":
+                   "projected": {"model_overlap_gain":
                                  model["overlap_gain"],
                                  "T_serial": model["T_serial"],
                                  "T_overlap": model["T_overlap"]},
-                   "overlap_efficiency": overlap_eff,
                    "teps_ratio": meas["teps_ratio"]}, f, indent=2)
 
     if ci:
@@ -213,8 +185,3 @@ def mesh():
         assert meas["teps_ratio"] >= 1.15, (
             "overlapped TEPS only %.2fx of synchronous (< 1.15x)"
             % meas["teps_ratio"])
-        assert overlap_eff >= 0.7, (
-            "measured overlapped superstep %.0fus vs projected floor "
-            "%.0fus: efficiency %.2f < 0.7"
-            % (meas["superstep_ov_s"] * 1e6, proj["overlapped_s"] * 1e6,
-               overlap_eff))

@@ -3,9 +3,10 @@
 Keeps the observability vocabulary closed and documented:
 
 * **TAX001** unknown trace kind: every ``bus.emit("<kind>", ...)`` /
-  ``self._emit("<kind>", ...)`` string literal must be a member of
-  ``trace.EVENT_KINDS`` (the runtime asserts this too, but only on the
-  paths a test happens to drive).
+  ``self._emit("<kind>", ...)`` / ``bus.span("<kind>", ...)`` (the
+  first string literal among the positional arguments) must be a member
+  of ``trace.EVENT_KINDS`` (the runtime asserts this too, but only on
+  the paths a test happens to drive).
 * **TAX002** malformed metric name: every emitted ``gravfm_*`` name
   must match ``^gravfm_[a-z0-9_]+$``.
 * **TAX003** suffix/type mismatch: counters end ``_total``;
@@ -39,7 +40,7 @@ __all__ = ["TaxonomyPass", "parse_readme_metrics", "parse_readme_kinds"]
 _NAME_RE = re.compile(r"^gravfm_[a-z0-9_]+$")
 _TICK_RE = re.compile(r"`([^`]+)`")
 
-_EMIT_METHODS = {"emit", "_emit"}
+_EMIT_METHODS = {"emit", "_emit", "span", "_span"}
 _METRIC_METHODS = {"inc": "counter", "set_counter": "counter",
                    "set_gauge": "gauge", "observe": "histogram"}
 
@@ -271,9 +272,8 @@ class TaxonomyPass:
                 scope = getattr(fn, "name", "<module>")
                 # ---- trace kinds --------------------------------
                 if method in _EMIT_METHODS and kinds is not None:
-                    arg = None
-                    if node.args:
-                        arg = node.args[0]
+                    arg = next((a for a in node.args
+                                if _literal_str(a) is not None), None)
                     for kw in node.keywords:
                         if kw.arg == "kind":
                             arg = kw.value

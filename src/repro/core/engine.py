@@ -27,9 +27,11 @@ distributed termination is the all-reduced "no shard sent updates" bit.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import time
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +47,24 @@ from .stepper import LaneStepper, SuperstepProgram
 __all__ = ["Engine", "EngineResult", "collect"]
 
 HARD_SUPERSTEP_CAP = 100_000
+
+
+def span(bus, kind: str):
+    """``bus.span(kind)`` on an engine's duck-typed event bus (the
+    service's ``TraceBus``, which the plan cache attaches), or a no-op
+    for an engine that has none."""
+    return contextlib.nullcontext() if bus is None else bus.span(kind)
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the ``__name__`` ``name`` (its signature kept):
+    ``jax.jit`` names the XLA module after it, and the profiler's "XLA
+    Modules" line shows that name for every execution of the program."""
+    @functools.wraps(fn)
+    def f(*args):
+        return fn(*args)
+    f.__name__ = f.__qualname__ = name
+    return f
 
 
 class _GravfmData(NamedTuple):
@@ -119,6 +139,7 @@ class Engine:
                  params: Optional[Dict[str, Any]] = None):
         assert mode in ("gravf", "gravfm")
         assert backend in ("pallas", "ref")
+        self._name = f"{kernel.name}_{mode}"
         self.kernel = kernel
         self.pg = pg
         self.mode = mode
@@ -146,15 +167,14 @@ class Engine:
         # performs zero re-traces against this.
         self.traces = 0
         self._device_resident = True
+        # event bus for the execute/fetch/collect spans of run/run_batch
+        # (duck-typed; the plan cache attaches the service's TraceBus)
+        self.trace = None
         self._prog = self._make_program()
         self._steppers: Dict[int, LaneStepper] = {}
-        loop = self._make_loop()
-        self._step = jax.jit(loop)
-        # Batched variant: a leading query axis on the per-query kwargs.
-        # vmap of the while_loop freezes finished queries' carries (their
-        # cond is False), so quiescent queries ride along at zero semantic
-        # cost until the whole batch terminates.
-        self._batch_step = jax.jit(jax.vmap(loop, in_axes=(None, None, 0)))
+        self._loop = self._make_loop()
+        self._step = jax.jit(named(self._loop, f"{self._name}_run"))
+        self._batch_steps: Dict[int, Any] = {}
 
     # ------------------------------------------------------------------
     def _build_gravfm(self, flt_cnt, tile_e, tile_r) -> _GravfmData:
@@ -270,8 +290,10 @@ class Engine:
             carry_full = self._combine(data, cmasked, "min")
             carry = carry_full.reshape(P, Vm + 1)[:, :Vm]
 
-        n_msgs = jnp.sum(act.astype(jnp.int32))
-        n_remote_msgs = jnp.sum((act & data.lane_remote).astype(jnp.int32))
+        with jax.named_scope("gravfm.stats"):
+            n_msgs = jnp.sum(act.astype(jnp.int32))
+            n_remote_msgs = jnp.sum(
+                (act & data.lane_remote).astype(jnp.int32))
         return acc, got, carry, {"n_msgs": n_msgs, "n_remote": n_remote_msgs}
 
     def _deliver_gravf(self, data: _GravfData, payload, active):  # analysis: traced
@@ -319,9 +341,10 @@ class Engine:
                 cmasked.reshape(-1), seg.reshape(-1), S, "min")
             carry = carry_full.reshape(P, Vm + 1)[:, :Vm]
 
-        n_msgs = jnp.sum(act.astype(jnp.int32))
-        cross = ~jnp.eye(P, dtype=bool)[:, :, None]
-        n_remote = jnp.sum((act & cross).astype(jnp.int32))
+        with jax.named_scope("gravfm.stats"):
+            n_msgs = jnp.sum(act.astype(jnp.int32))
+            cross = ~jnp.eye(P, dtype=bool)[:, :, None]
+            n_remote = jnp.sum((act & cross).astype(jnp.int32))
         return acc, got, carry, {"n_msgs": n_msgs, "n_remote": n_remote}
 
     # ------------------------------------------------------------------
@@ -361,6 +384,20 @@ class Engine:
         return SuperstepProgram(self.kernel, deliver,
                                 init_stats=init_stats,
                                 update_stats=update_stats)
+
+    def _make_batch_program(self, batch: int):
+        """The jitted program run_batch dispatches for ``batch`` queries:
+        a leading query axis on the per-query kwargs. vmap of the
+        while_loop freezes finished queries' carries (their cond is
+        False), so quiescent queries ride along at zero semantic cost
+        until the whole batch terminates. One jit per batch size, so
+        that each compiled program has its own module name."""
+        fn = self._batch_steps.get(batch)
+        if fn is None:
+            fn = self._batch_steps[batch] = jax.jit(jax.vmap(
+                named(self._loop, f"{self._name}_batch{batch}"),
+                in_axes=(None, None, 0)))
+        return fn
 
     def _make_loop(self):
         prog = self._prog
@@ -438,21 +475,23 @@ class Engine:
         cap = max_supersteps or self.kernel.max_supersteps or HARD_SUPERSTEP_CAP
         self._check_query_kwargs(query_kwargs)
         qkw = {kk: jnp.asarray(v) for kk, v in query_kwargs.items()}
-        state, s, stats = self._step(self._data, jnp.int32(cap), qkw)
-        state = jax.tree.map(np.asarray, state)
-        comm_scheme = ("gravfm_broadcast" if self.mode == "gravfm"
-                       else "gravf_unicast")
-        comm = {kk: float(v) for kk, v in jax.tree.map(np.asarray,
-                                                       stats).items()}
-        comm["scheme"] = comm_scheme
-        comm["wire_words"] = comm[self.wire_stat]
-        return EngineResult(
-            state=collect(self.pg, state),
-            supersteps=int(s),
-            messages=int(stats["messages"]),
-            comm=comm,
-            raw_state=state,
-        )
+        with span(self.trace, "execute"):
+            out = self._step(self._data, jnp.int32(cap), qkw)
+            jax.block_until_ready(out)
+        with span(self.trace, "fetch"):
+            state, s, stats = jax.tree.map(np.asarray, out)
+        with span(self.trace, "collect"):
+            comm = {kk: float(v) for kk, v in stats.items()}
+            comm["scheme"] = ("gravfm_broadcast" if self.mode == "gravfm"
+                              else "gravf_unicast")
+            comm["wire_words"] = comm[self.wire_stat]
+            return EngineResult(
+                state=collect(self.pg, state),
+                supersteps=int(s),
+                messages=int(stats["messages"]),
+                comm=comm,
+                raw_state=state,
+            )
 
     def run_batch(self, max_supersteps: Optional[int] = None,
                   **query_arrays) -> "list[EngineResult]":
@@ -478,26 +517,29 @@ class Engine:
         batch = next(iter(sizes.values()))
         if any(b != batch for b in sizes.values()):
             raise ValueError(f"inconsistent query batch sizes: {sizes}")
-        state, s, stats = self._batch_step(self._data, jnp.int32(cap), qkw)
-        state = jax.tree.map(np.asarray, state)
-        s = np.asarray(s)
-        stats = jax.tree.map(np.asarray, stats)
-        comm_scheme = ("gravfm_broadcast" if self.mode == "gravfm"
-                       else "gravf_unicast")
-        results = []
-        for q in range(batch):
-            state_q = jax.tree.map(lambda a: a[q], state)
-            comm = {kk: float(v[q]) for kk, v in stats.items()}
-            comm["scheme"] = comm_scheme
-            comm["wire_words"] = comm[self.wire_stat]
-            results.append(EngineResult(
-                state=collect(self.pg, state_q),
-                supersteps=int(s[q]),
-                messages=int(stats["messages"][q]),
-                comm=comm,
-                raw_state=state_q,
-            ))
-        return results
+        with span(self.trace, "execute"):
+            out = self._make_batch_program(batch)(self._data, jnp.int32(cap),
+                                             qkw)
+            jax.block_until_ready(out)
+        with span(self.trace, "fetch"):
+            state, s, stats = jax.tree.map(np.asarray, out)
+        with span(self.trace, "collect"):
+            comm_scheme = ("gravfm_broadcast" if self.mode == "gravfm"
+                           else "gravf_unicast")
+            results = []
+            for q in range(batch):
+                state_q = jax.tree.map(lambda a: a[q], state)
+                comm = {kk: float(v[q]) for kk, v in stats.items()}
+                comm["scheme"] = comm_scheme
+                comm["wire_words"] = comm[self.wire_stat]
+                results.append(EngineResult(
+                    state=collect(self.pg, state_q),
+                    supersteps=int(s[q]),
+                    messages=int(stats["messages"][q]),
+                    comm=comm,
+                    raw_state=state_q,
+                ))
+            return results
 
     def lower_batch(self, batch: int) -> jax.stages.Lowered:
         """Lower the program :meth:`run_batch` dispatches for ``batch``
@@ -508,7 +550,21 @@ class Engine:
         qkw = {p: jax.ShapeDtypeStruct((batch,), jnp.int32)
                for p in self.kernel.query_params}
         cap = self.kernel.max_supersteps or HARD_SUPERSTEP_CAP
-        return self._batch_step.lower(self._data, jnp.int32(cap), qkw)
+        return self._make_batch_program(batch).lower(
+            self._data, jnp.int32(cap), qkw)
+
+    def lower(self, batch: int) -> jax.stages.Lowered:
+        """Lower the program a plan of ``batch`` queries dispatches with
+        int32 query parameters: :meth:`run`'s for one query,
+        :meth:`run_batch`'s otherwise. It shares JAX's trace and compile
+        caches with that dispatch, so compiling it first costs the
+        dispatch nothing more."""
+        if batch > 1:
+            return self.lower_batch(batch)
+        qkw = {p: jax.ShapeDtypeStruct((), jnp.int32)
+               for p in self.kernel.query_params}
+        cap = self.kernel.max_supersteps or HARD_SUPERSTEP_CAP
+        return self._step.lower(self._data, jnp.int32(cap), qkw)
 
     # ------------------------------------------------------------------
     def make_stepper(self, width: int) -> LaneStepper:

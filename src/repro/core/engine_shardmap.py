@@ -78,6 +78,8 @@ import dataclasses
 import time
 from typing import Any, Dict, NamedTuple, Optional
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,6 +88,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..kernels import ops as kops
 from ..kernels import ref as kref
+from .engine import named, span
 from .gas import GasKernel
 from .partition import PartitionedGraph
 from .stepper import (LaneStepperBase, StepCarry, SuperstepProgram,
@@ -95,6 +98,29 @@ __all__ = ["ShardEngine", "ShardLaneStepper", "build_shard_data",
            "ShardData"]
 
 AXIS = "graph"
+
+def _exchange_op(op):
+    """``op``, a ``jax.lax`` collective, under the ``gravfm.exchange``
+    device scope: the exchange's device time is then named apart from
+    the edge pass (``gravfm.deliver``) it is interleaved with."""
+    def f(*args, **kwargs):
+        with jax.named_scope("gravfm.exchange"):
+            return op(*args, **kwargs)
+    return f
+
+
+# the collectives every exchange runs on, each under gravfm.exchange
+xc = types.SimpleNamespace(**{
+    name: _exchange_op(getattr(jax.lax, name))
+    for name in ("all_gather", "all_to_all", "ppermute", "pmax")})
+
+
+def _count(mask):
+    """The messages a mask carries, for the stats: counter work, under
+    the ``gravfm.stats`` scope inside the edge pass."""
+    with jax.named_scope("gravfm.stats"):
+        return jnp.sum(mask.astype(jnp.int32))
+
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
     # invoked only from _build-time factories
@@ -403,6 +429,9 @@ class ShardEngine:
         # jitted program cache (per superstep cap) + trace counter; see
         # Engine.traces for the counting trick.
         self.traces = 0
+        # event bus for run/run_batch's execute/fetch/collect spans (see
+        # Engine.trace)
+        self.trace = None
         self._run_cache: Dict[Any, Any] = {}
         # one program per schedule; the overlapped variant is built
         # lazily (its windowed folds require a min/max combiner) and
@@ -509,14 +538,14 @@ class ShardEngine:
                 acc_pad, jnp.minimum(d.seg, m.v_max)))
             cmasked = jnp.where(winner, cvals, cident)
             carry = self._local_combine(cmasked, d, "min")[: m.v_max]
-        n_msgs = jnp.sum(act.astype(jnp.int32))
+        n_msgs = _count(act)
         return acc, got, carry, n_msgs
 
     # ---------------- exchanges ---------------------------------------
     def _deliver_allgather(self, d, payload, active):  # analysis: traced
         m = self.meta
-        upd = jax.lax.all_gather(payload, AXIS)          # (P, Vm)
-        act = jax.lax.all_gather(active, AXIS)
+        upd = xc.all_gather(payload, AXIS)          # (P, Vm)
+        act = xc.all_gather(active, AXIS)
         # actual wire: the DENSE padded update array goes to every peer
         words = jnp.float32(m.v_max * (m.P - 1))
         acc, got, carry, n_msgs = self._consume(
@@ -529,7 +558,7 @@ class ShardEngine:
         k, m = self.kernel, self.meta
         me = jax.lax.axis_index(AXIS)
         n_act = jnp.sum(active.astype(jnp.int32))
-        n_max = jax.lax.pmax(n_act, AXIS)
+        n_max = xc.pmax(n_act, AXIS)
         caps = m.frontier_capacities
         ident = kops.identity_for(k.combiner, k.msg_dtype)
 
@@ -544,9 +573,9 @@ class ShardEngine:
                 pay = jnp.take(payload, safe)
                 slots = me * m.v_max + safe
                 # broadcast the COMPACT (id, payload) buffer only
-                slots_all = jax.lax.all_gather(slots, AXIS).reshape(-1)
-                pay_all = jax.lax.all_gather(pay, AXIS).reshape(-1)
-                val_all = jax.lax.all_gather(valid, AXIS).reshape(-1)
+                slots_all = xc.all_gather(slots, AXIS).reshape(-1)
+                pay_all = xc.all_gather(pay, AXIS).reshape(-1)
+                val_all = xc.all_gather(valid, AXIS).reshape(-1)
                 tgt = jnp.where(val_all, slots_all, drop)
                 # each slot has a unique owner => plain scatter-set is exact
                 pf = jnp.full((m.P * m.v_max,), ident, pay_all.dtype)
@@ -614,7 +643,7 @@ class ShardEngine:
                                             jnp.minimum(seg, m.v_max)))
             car_q = kref.segment_combine(
                 jnp.where(win, cvals, cident), seg, m.v_max, "min")
-        return acc_q, gv > 0, car_q, jnp.sum(act.astype(jnp.int32))
+        return acc_q, gv > 0, car_q, _count(act)
 
     def _deliver_ring(self, d, payload, active):  # analysis: traced
         """P-hop ppermute ring; each arriving chunk is consumed against the
@@ -642,8 +671,8 @@ class ShardEngine:
             n_msgs = n_msgs + nm
             # next hop in flight while (in the compiled TPU schedule) the
             # next bucket's compute proceeds
-            chunk_p = jax.lax.ppermute(chunk_p, AXIS, perm)
-            chunk_a = jax.lax.ppermute(chunk_a, AXIS, perm)
+            chunk_p = xc.ppermute(chunk_p, AXIS, perm)
+            chunk_a = xc.ppermute(chunk_a, AXIS, perm)
             return acc, got, n_msgs, chunk_p, chunk_a, ccar
 
         acc0 = jnp.full((m.v_max,), ident, k.msg_dtype)
@@ -668,9 +697,9 @@ class ShardEngine:
         msg = k.scatter(vals, d.pair_w, d.pair_src_gid, d.pair_src_outdeg)
         ident = kops.identity_for(k.combiner, k.msg_dtype)
         masked = jnp.where(act, msg, ident)
-        recv = jax.lax.all_to_all(masked, AXIS, split_axis=0,
+        recv = xc.all_to_all(masked, AXIS, split_axis=0,
                                   concat_axis=0, tiled=False)
-        recv_act = jax.lax.all_to_all(act, AXIS, split_axis=0,
+        recv_act = xc.all_to_all(act, AXIS, split_axis=0,
                                       concat_axis=0, tiled=False)
         seg = d.recv_dst_local
         acc = kref.segment_combine(recv.reshape(-1), seg.reshape(-1),
@@ -684,7 +713,7 @@ class ShardEngine:
             cident = kops.identity_for("min", k.carry_dtype)
             cvals = k.scatter_carry(vals, d.pair_w, d.pair_src_gid,
                                     d.pair_src_outdeg)
-            crecv = jax.lax.all_to_all(jnp.where(act, cvals, cident), AXIS,
+            crecv = xc.all_to_all(jnp.where(act, cvals, cident), AXIS,
                                        split_axis=0, concat_axis=0,
                                        tiled=False)
             acc_pad = jnp.concatenate([acc, jnp.full((1,), ident, acc.dtype)])
@@ -693,7 +722,7 @@ class ShardEngine:
             carry = kref.segment_combine(
                 jnp.where(winner, crecv, cident).reshape(-1),
                 seg.reshape(-1), m.v_max, "min")
-        n_msgs = jnp.sum(act.astype(jnp.int32))
+        n_msgs = _count(act)
         # actual wire: all_to_all ships the PADDED per-pair blocks
         words = jnp.float32(m.e_pair_max * (m.P - 1))
         return acc, got, carry, {"n_msgs": n_msgs, "words": words}
@@ -719,9 +748,9 @@ class ShardEngine:
         send_act = self._comb_combine(
             jnp.where(act, 1, 0).astype(jnp.int32), d, "max"
         ).reshape(m.P, R + 1)[:, :R] > 0
-        recv = jax.lax.all_to_all(send, AXIS, split_axis=0,
+        recv = xc.all_to_all(send, AXIS, split_axis=0,
                                   concat_axis=0, tiled=False)
-        recv_act = jax.lax.all_to_all(send_act, AXIS, split_axis=0,
+        recv_act = xc.all_to_all(send_act, AXIS, split_axis=0,
                                       concat_axis=0, tiled=False)
         seg = d.comb_recv_dst_local                            # (P, R)
         acc = kref.segment_combine(recv.reshape(-1), seg.reshape(-1),
@@ -746,7 +775,7 @@ class ShardEngine:
             csend = self._comb_combine(
                 jnp.where(win, cvals, cident), d, "min"
             ).reshape(m.P, R + 1)[:, :R]
-            crecv = jax.lax.all_to_all(csend, AXIS, split_axis=0,
+            crecv = xc.all_to_all(csend, AXIS, split_axis=0,
                                        concat_axis=0, tiled=False)
             acc_pad = jnp.concatenate(
                 [acc, jnp.full((1,), ident, acc.dtype)])
@@ -755,7 +784,7 @@ class ShardEngine:
             carry = kref.segment_combine(
                 jnp.where(winner, crecv, cident).reshape(-1),
                 seg.reshape(-1), m.v_max, "min")
-        n_msgs = jnp.sum(act.astype(jnp.int32))
+        n_msgs = _count(act)
         # actual wire: one (id, payload) slot per padded remote dst —
         # the degree-factor win over unicast's e_pair_max per-edge blocks
         words = jnp.float32(2 * R * (m.P - 1))
@@ -784,8 +813,8 @@ class ShardEngine:
             upd, actf, cur_p, cur_a, nxt_p, nxt_a = st
             # hop i+2's transport first: the in-flight buffer moves on
             # while chunk i is being placed (double buffer)
-            new_p = jax.lax.ppermute(nxt_p, AXIS, perm)
-            new_a = jax.lax.ppermute(nxt_a, AXIS, perm)
+            new_p = xc.ppermute(nxt_p, AXIS, perm)
+            new_a = xc.ppermute(nxt_a, AXIS, perm)
             q = (me - i) % m.P
             upd = jax.lax.dynamic_update_slice(upd, cur_p, (q * m.v_max,))
             actf = jax.lax.dynamic_update_slice(actf, cur_a, (q * m.v_max,))
@@ -794,8 +823,8 @@ class ShardEngine:
         st = (jnp.zeros((m.P * m.v_max,), payload.dtype),
               jnp.zeros((m.P * m.v_max,), jnp.bool_),
               payload, active,
-              jax.lax.ppermute(payload, AXIS, perm),
-              jax.lax.ppermute(active, AXIS, perm))
+              xc.ppermute(payload, AXIS, perm),
+              xc.ppermute(active, AXIS, perm))
         upd, actf = jax.lax.fori_loop(0, m.P, body, st)[:2]
         words = jnp.float32(m.v_max * (m.P - 1))
         acc, got, carry, n_msgs = self._consume(d, upd, actf)
@@ -810,7 +839,7 @@ class ShardEngine:
         k, m = self.kernel, self.meta
         me = jax.lax.axis_index(AXIS)
         n_act = jnp.sum(active.astype(jnp.int32))
-        n_max = jax.lax.pmax(n_act, AXIS)
+        n_max = xc.pmax(n_act, AXIS)
         caps = m.frontier_capacities
         ident = kops.identity_for(k.combiner, k.msg_dtype)
         perm = [(i, (i + 1) % m.P) for i in range(m.P)]
@@ -828,9 +857,9 @@ class ShardEngine:
 
                 def body(i, st):
                     pf, af, cs, cp, cv, ns, np_, nv = st
-                    ms = jax.lax.ppermute(ns, AXIS, perm)
-                    mp = jax.lax.ppermute(np_, AXIS, perm)
-                    mv = jax.lax.ppermute(nv, AXIS, perm)
+                    ms = xc.ppermute(ns, AXIS, perm)
+                    mp = xc.ppermute(np_, AXIS, perm)
+                    mv = xc.ppermute(nv, AXIS, perm)
                     tgt = jnp.where(cv, cs, drop)
                     pf = pf.at[tgt].set(cp, mode="drop")
                     af = af.at[tgt].set(True, mode="drop")
@@ -839,9 +868,9 @@ class ShardEngine:
                 st = (jnp.full((m.P * m.v_max,), ident, pay.dtype),
                       jnp.zeros((m.P * m.v_max,), jnp.bool_),
                       slots, pay, valid,
-                      jax.lax.ppermute(slots, AXIS, perm),
-                      jax.lax.ppermute(pay, AXIS, perm),
-                      jax.lax.ppermute(valid, AXIS, perm))
+                      xc.ppermute(slots, AXIS, perm),
+                      xc.ppermute(pay, AXIS, perm),
+                      xc.ppermute(valid, AXIS, perm))
                 pf, af = jax.lax.fori_loop(0, m.P, body, st)[:2]
                 # wire words actually moved: identical to the sync path
                 words = jnp.float32(cap * 2 * (m.P - 1))
@@ -869,8 +898,8 @@ class ShardEngine:
         def body(i, st):
             acc, got, n_msgs, cur_p, cur_a, nxt_p, nxt_a, ccar = st
             # issue hop i+2's transport before touching chunk i
-            new_p = jax.lax.ppermute(nxt_p, AXIS, perm)
-            new_a = jax.lax.ppermute(nxt_a, AXIS, perm)
+            new_p = xc.ppermute(nxt_p, AXIS, perm)
+            new_a = xc.ppermute(nxt_a, AXIS, perm)
             q = (me - i) % m.P
             acc_q, got_q, car_q, nm = self._ring_bucket_consume(
                 d, q, cur_p, cur_a)
@@ -887,8 +916,8 @@ class ShardEngine:
         ccar0 = (jnp.full((m.v_max,), cident, k.carry_dtype)
                  if k.carry_dtype is not None else jnp.int32(0))
         st = (acc0, got0, jnp.int32(0), payload, active,
-              jax.lax.ppermute(payload, AXIS, perm),
-              jax.lax.ppermute(active, AXIS, perm), ccar0)
+              xc.ppermute(payload, AXIS, perm),
+              xc.ppermute(active, AXIS, perm), ccar0)
         st = jax.lax.fori_loop(0, m.P, body, st)
         acc, got, n_msgs = st[0], st[1], st[2]
         ccar = st[7]
@@ -910,7 +939,7 @@ class ShardEngine:
         dummy = jnp.int32(0)
 
         def a2a(x):
-            return jax.lax.all_to_all(x, AXIS, split_axis=0,
+            return xc.all_to_all(x, AXIS, split_axis=0,
                                       concat_axis=0, tiled=False)
 
         def issue(wi):
@@ -1006,7 +1035,7 @@ class ShardEngine:
                                cident)
         acc, got, carry = self._window_pipeline(
             seg3, masked3, act3, c3, n_win, ident, cident)
-        n_msgs = jnp.sum(act.astype(jnp.int32))
+        n_msgs = _count(act)
         # reported wire: the bytes the serial schedule moves (see module
         # docstring) — keeps stats comparable across schedules
         words = jnp.float32(m.e_pair_max * (m.P - 1))
@@ -1053,7 +1082,7 @@ class ShardEngine:
             c3 = self._window3(csend, n_win, cw, cident)
         acc, got, carry = self._window_pipeline(
             seg3, masked3, act3, c3, n_win, ident, cident)
-        n_msgs = jnp.sum(act.astype(jnp.int32))
+        n_msgs = _count(act)
         words = jnp.float32(2 * R * (m.P - 1))
         return acc, got, carry, {"n_msgs": n_msgs, "words": words}
 
@@ -1084,26 +1113,26 @@ class ShardEngine:
             state = jax.tree.map(lambda a: a[None], c.state)
             return state, c.superstep, total_msgs, total_words
 
-        m = self.meta
         in_specs = jax.tree.map(lambda _: P(AXIS), self._data,
                                 is_leaf=lambda x: x is None)
         qspec = {kk: P() for kk in qkeys}
         state_spec = P(AXIS)
         fn = _shard_map(
-            shard_fn, mesh=self.mesh,
+            named(shard_fn, self._program_name("run", overlap)),
+            mesh=self.mesh,
             in_specs=(in_specs, qspec),
             out_specs=(state_spec, P(), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
 
-    def _make_run_batch(self, cap: int, qkeys: tuple,
+    def _make_run_batch(self, cap: int, qkeys: tuple, batch: int,
                         overlap: bool = False):
         """Query-batched shard_map program: the per-superstep exchange is
         shared by all B queries (one collective moves the (B, ·) payload);
         finished queries are frozen lane-wise so state/stats stay
         bit-identical to B sequential runs."""
-        ck = ("batch", cap, qkeys, bool(overlap))
+        ck = ("batch", cap, qkeys, batch, bool(overlap))
         if ck in self._run_cache:
             return self._run_cache[ck]
         prog = self._prog_for(overlap)
@@ -1118,13 +1147,15 @@ class ShardEngine:
 
             def alive_of(c):
                 # per-query distributed termination bit (§4.3, per lane)
-                loc = jnp.any(c.active, axis=-1).astype(jnp.int32)  # (B,)
-                return jax.lax.pmax(loc, AXIS) > 0
+                with jax.named_scope("gravfm.cond"):
+                    loc = jnp.any(c.active, axis=-1).astype(jnp.int32)
+                    return jax.lax.pmax(loc, AXIS) > 0       # (B,)
 
             def cond(st):
                 s, c = st
-                any_local = jnp.any(c.active).astype(jnp.int32)
-                return (jax.lax.pmax(any_local, AXIS) > 0) & (s < cap)
+                with jax.named_scope("gravfm.cond"):
+                    any_local = jnp.any(c.active).astype(jnp.int32)
+                    return (jax.lax.pmax(any_local, AXIS) > 0) & (s < cap)
 
             def body(st):
                 s, c = st
@@ -1134,8 +1165,9 @@ class ShardEngine:
                 c = select_lanes(alive_of(c), step_v(c), c)
                 return s + 1, c
 
-            _, carry = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), carry))
+            with jax.named_scope("gravfm.loop"):
+                _, carry = jax.lax.while_loop(
+                    cond, body, (jnp.int32(0), carry))
             total_msgs = jax.lax.psum(carry.stats["messages"], AXIS)  # (B,)
             total_words = jax.lax.psum(
                 jnp.sum(carry.stats["words"]), AXIS)
@@ -1147,12 +1179,30 @@ class ShardEngine:
                                 is_leaf=lambda x: x is None)
         qspec = {kk: P() for kk in qkeys}
         fn = _shard_map(
-            shard_fn, mesh=self.mesh,
+            named(shard_fn, self._program_name(f"batch{batch}", overlap)),
+            mesh=self.mesh,
             in_specs=(in_specs, qspec),
             out_specs=(P(AXIS), P(), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
+
+    def _program_name(self, kind: str, overlap: bool) -> str:
+        return (f"{self.kernel.name}_{self.exchange}"
+                f"{'_overlap' if overlap else ''}_{kind}")
+
+    def lower(self, batch: int, overlap: bool = False
+              ) -> jax.stages.Lowered:
+        """Lower the program a plan of ``batch`` queries dispatches with
+        int32 query parameters (see ``Engine.lower``): :meth:`run`'s for
+        one query, :meth:`run_batch`'s otherwise."""
+        cap = self.kernel.max_supersteps or 100_000
+        qkeys = tuple(sorted(self.kernel.query_params))
+        shape = () if batch == 1 else (batch,)
+        qkw = {k: jax.ShapeDtypeStruct(shape, jnp.int32) for k in qkeys}
+        fn = (self._make_run(cap, qkeys, overlap) if batch == 1 else
+              self._make_run_batch(cap, qkeys, batch, overlap))
+        return fn.lower(self._data, qkw)
 
     def _result_comm(self, words: float) -> Dict[str, Any]:
         return {"exchange_words": words, "wire_words": words,
@@ -1175,17 +1225,20 @@ class ShardEngine:
         cap = (max_supersteps or self.kernel.max_supersteps or 100_000)
         qkw = {kk: jnp.asarray(v) for kk, v in query_kwargs.items()}
         fn = self._make_run(cap, tuple(sorted(qkw)), overlap)
-        state, s, msgs, words = fn(self._data, qkw)
+        with span(self.trace, "execute"):
+            out = fn(self._data, qkw)
+            jax.block_until_ready(out)
+        with span(self.trace, "fetch"):
+            state_np, s, msgs, words = jax.tree.map(np.asarray, out)
         from .engine import EngineResult, collect
-        state_np = jax.tree.map(np.asarray, state)
-        return EngineResult(
-            state=collect(self.pg, state_np) if self.pg else state_np,
-            supersteps=int(np.asarray(s)[0] if np.ndim(s) else s),
-            messages=int(np.asarray(msgs).reshape(-1)[0]),
-            comm=self._result_comm(
-                float(np.asarray(words).reshape(-1)[0])),
-            raw_state=state_np,
-        )
+        with span(self.trace, "collect"):
+            return EngineResult(
+                state=collect(self.pg, state_np) if self.pg else state_np,
+                supersteps=int(s[0] if np.ndim(s) else s),
+                messages=int(msgs.reshape(-1)[0]),
+                comm=self._result_comm(float(words.reshape(-1)[0])),
+                raw_state=state_np,
+            )
 
     def run_batch(self, max_supersteps: Optional[int] = None,
                   overlap: bool = False, **query_arrays):
@@ -1203,24 +1256,30 @@ class ShardEngine:
         cap = (max_supersteps or self.kernel.max_supersteps or 100_000)
         qkw = {kk: jnp.atleast_1d(jnp.asarray(v))
                for kk, v in query_arrays.items()}
-        fn = self._make_run_batch(cap, tuple(sorted(qkw)), overlap)
-        state, sq, msgs, words = fn(self._data, qkw)
+        batch = next(iter(qkw.values())).shape[0]
+        fn = self._make_run_batch(cap, tuple(sorted(qkw)), batch, overlap)
+        with span(self.trace, "execute"):
+            res = fn(self._data, qkw)
+            jax.block_until_ready(res)
+        with span(self.trace, "fetch"):
+            # state leaves (P, B, ...)
+            state_np, sq, msgs, words = jax.tree.map(np.asarray, res)
         from .engine import EngineResult, collect
-        state_np = jax.tree.map(np.asarray, state)   # leaves (P, B, ...)
-        sq = np.asarray(sq).reshape(-1, np.asarray(sq).shape[-1])[0]
-        msgs = np.asarray(msgs).reshape(-1, np.asarray(msgs).shape[-1])[0]
-        words = float(np.asarray(words).reshape(-1)[0])
-        out = []
-        for q in range(sq.shape[0]):
-            state_q = jax.tree.map(lambda a: a[:, q], state_np)
-            out.append(EngineResult(
-                state=collect(self.pg, state_q) if self.pg else state_q,
-                supersteps=int(sq[q]),
-                messages=int(msgs[q]),
-                comm=self._result_comm(words),
-                raw_state=state_q,
-            ))
-        return out
+        with span(self.trace, "collect"):
+            sq = sq.reshape(-1, sq.shape[-1])[0]
+            msgs = msgs.reshape(-1, msgs.shape[-1])[0]
+            words = float(words.reshape(-1)[0])
+            out = []
+            for q in range(sq.shape[0]):
+                state_q = jax.tree.map(lambda a: a[:, q], state_np)
+                out.append(EngineResult(
+                    state=collect(self.pg, state_q) if self.pg else state_q,
+                    supersteps=int(sq[q]),
+                    messages=int(msgs[q]),
+                    comm=self._result_comm(words),
+                    raw_state=state_q,
+                ))
+            return out
 
     @property
     def device_nbytes(self) -> int:
@@ -1328,7 +1387,6 @@ class ShardLaneStepper(LaneStepperBase):
         self._prog = eng._prog_for(self.overlap)
         self._fns = None  # (init, admit, step) jitted shard_map programs
         self._restore = None   # built with the other programs
-        self._exchange_serial_p = None  # profile-only serial reference
         self._probe = jax.jit(self._probe_of)
 
         def fetch_lane_fn(carry, lane):
@@ -1420,52 +1478,6 @@ class ShardLaneStepper(LaneStepperBase):
                                           lane_spec),
                                 out_specs=carry_spec)
 
-        # profiled-mode phase programs: the superstep cut at the
-        # exchange/apply boundary. Inside shard_map the collective and
-        # the receiver-side combine cannot be host-separated (the
-        # delivered intermediates only exist per-shard), so the shard
-        # profile is exchange (deliver + gather-combine, the L_if/L_net
-        # + part of L_node term) then apply. The exchange output is
-        # carry-shaped (step counter advances in apply), so both
-        # programs run carry_spec -> carry_spec.
-        def exchange_fn(d, carry):
-            eng.traces += 1
-            d, c = strip(d), strip(carry)
-            return readd(jax.vmap(
-                lambda cc: prog.step_exchange(d, cc))(c))
-
-        def apply_fn(d, carry, mid, alive):
-            eng.traces += 1
-            d, c, m = strip(d), strip(carry), strip(mid)
-            return readd(select_lanes(
-                alive, jax.vmap(lambda cc: prog.step_apply(d, cc))(m), c))
-
-        exchange_sm = _shard_map(exchange_fn, mesh=eng.mesh,
-                                 in_specs=(data_spec, carry_spec),
-                                 out_specs=carry_spec)
-        apply_sm = _shard_map(apply_fn, mesh=eng.mesh,
-                              in_specs=(data_spec, carry_spec,
-                                        carry_spec, lane_spec),
-                              out_specs=carry_spec)
-
-        # overlapped steppers keep a serial-schedule exchange reference
-        # for the phase profiler: timing it on the same carry (output
-        # unused — the schedules are bit-identical) yields the
-        # total-exchange-time denominator of overlap_efficiency. Only
-        # ever dispatched in profile mode, off the serving hot path.
-        if self.overlap:
-            sprog = eng._prog_for(False)
-
-            def exchange_serial_fn(d, carry):
-                eng.traces += 1
-                d, c = strip(d), strip(carry)
-                return readd(jax.vmap(
-                    lambda cc: sprog.step_exchange(d, cc))(c))
-
-            self._exchange_serial_p = jax.jit(_shard_map(
-                exchange_serial_fn, mesh=eng.mesh,
-                in_specs=(data_spec, carry_spec), out_specs=carry_spec))
-
         # fuse the lane probe into the same dispatch (see LaneStepper)
         def with_probe(sm):
             def f(*args):
@@ -1476,8 +1488,6 @@ class ShardLaneStepper(LaneStepperBase):
         self._fns = (with_probe(init_sm), with_probe(admit_sm),
                      with_probe(step_sm))
         self._restore = with_probe(restore_sm)
-        self._exchange_p = jax.jit(exchange_sm)
-        self._apply_p = jax.jit(apply_sm)
 
     def init(self, qkw):
         q = self._qdev(qkw)
@@ -1491,39 +1501,5 @@ class ShardLaneStepper(LaneStepperBase):
                                          jnp.asarray(fresh)))
 
     def step(self, carry, alive):
-        if not self.profile:
-            self.last_phases = None
-            return self._unpack(self._fns[2](self.eng._data, carry,
-                                             jnp.asarray(alive)))
-        return self._profiled_step(carry, alive)
-
-    def _profiled_step(self, carry, alive):
-        """Exchange/apply/probe with host-timed boundaries — the shard
-        twin of ``LaneStepper._profiled_step`` (same select/masking as
-        the fused program, bit-identical results)."""
-        d, alive_dev = self.eng._data, jnp.asarray(alive)
-        phases = {}
-        if self._exchange_serial_p is not None:
-            # total-exchange-time reference: the serial schedule on the
-            # same carry (bit-identical output, discarded)
-            t = time.perf_counter()
-            ser = self._exchange_serial_p(d, carry)
-            jax.block_until_ready(ser)
-            phases["exchange_serial"] = time.perf_counter() - t
-        t = time.perf_counter()
-        mid = self._exchange_p(d, carry)
-        jax.block_until_ready(mid)
-        now = time.perf_counter()
-        phases["exchange"] = now - t
-        t = now
-        new = self._apply_p(d, carry, mid, alive_dev)
-        jax.block_until_ready(new)
-        now = time.perf_counter()
-        phases["apply"] = now - t
-        t = now
-        out = self._probe(new)
-        act, steps = np.asarray(out[0]), np.asarray(out[1])
-        self.last_wire_words = float(np.asarray(out[2]))
-        phases["probe"] = time.perf_counter() - t
-        self.last_phases = phases
-        return new, act, steps
+        return self._unpack(self._fns[2](self.eng._data, carry,
+                                         jnp.asarray(alive)))

@@ -35,8 +35,7 @@ __all__ = [
     "optimize", "PAPER_PLATFORM", "TPU_V5E", "PAPER_ALGOS", "tpu_algo",
     "PLATFORMS_BY_DEVICE_KIND",
     "words_per_superstep", "traffic_reduction", "EXCHANGES",
-    "PHASE_TERMS", "phase_projection", "overlapped_limits",
-    "overlapped_projection",
+    "overlapped_limits",
 ]
 
 GiB = 1024.0 ** 3
@@ -290,32 +289,6 @@ def limits(platform: Platform, algo: AlgoProfile, wl: Workload, *,
             "T_sys": t_sys, "bottleneck": bottleneck}
 
 
-# Which §5 limit term a measured superstep phase exercises. The phase
-# profiler (core/stepper.py profiled mode) attributes superstep wall
-# time into these phases; mapping each onto its model term lets the
-# observability layer compare the measured split against ``limits()``
-# term by term (§6's roofline methodology, per term instead of per
-# T_sys). ``probe`` is pure host/dispatch overhead — no model term.
-PHASE_TERMS: Dict[str, Optional[str]] = {
-    "scatter": "L_mem",       # receiver-side scatter: memory traffic
-    "combine": "L_PE",        # gather-combine fold: PE compute (L_node)
-    "apply": "L_PE",          # vertex apply: PE compute (L_node)
-    "exchange": "L_if",       # shard collective: interface/network wire
-    "exchange_serial": "L_if",  # profiled overlapped steppers' serial-
-                                # reference exchange (overlap accounting)
-    "probe": None,            # host sync — outside the model
-}
-
-
-def phase_projection(lim: Dict[str, float]) -> Dict[str, Optional[float]]:
-    """Per-phase TEPS ceiling from a :func:`limits` dict: the model term
-    (eq. 1/2/3/6) each measured phase is bounded by, keyed like the
-    profiler's ``last_phases``. ``None`` for phases the model has no
-    term for (host overhead)."""
-    return {phase: (float(lim[term]) if term is not None else None)
-            for phase, term in PHASE_TERMS.items()}
-
-
 def overlapped_limits(lim: Dict[str, float]) -> Dict[str, float]:
     """Overlapped-pipeline projection from a :func:`limits` dict.
 
@@ -348,29 +321,6 @@ def overlapped_limits(lim: Dict[str, float]) -> Dict[str, float]:
     t_overlap = min(l_compute, l_wire)
     return {"T_serial": t_serial, "T_overlap": t_overlap,
             "overlap_gain": t_overlap / t_serial}
-
-
-def overlapped_projection(t_compute: float,
-                          t_wire: float) -> Dict[str, float]:
-    """Time-domain counterpart of :func:`overlapped_limits`, for
-    calibrating against PROFILED phase walls instead of model limits:
-    given one superstep's measured local-compute seconds (scatter +
-    combine + apply) and exchange seconds under the synchronous
-    schedule, project
-
-        serial_s     = t_compute + t_wire     (what synchronous pays)
-        overlapped_s = max(t_compute, t_wire) (the pipelined floor)
-
-    and the projected ``gain`` = serial_s/overlapped_s. The mesh
-    benchmark divides its measured overlapped superstep wall by
-    ``overlapped_s`` for the measured/projected roofline-efficiency
-    gate (the §6 methodology applied to the overlap claim)."""
-    t_compute = max(0.0, float(t_compute))
-    t_wire = max(0.0, float(t_wire))
-    serial = t_compute + t_wire
-    over = max(t_compute, t_wire)
-    return {"serial_s": serial, "overlapped_s": over,
-            "gain": serial / over if over > 0 else 1.0}
 
 
 def speedup_eq5(algo: AlgoProfile, wl: Workload, n_nodes: int) -> float:

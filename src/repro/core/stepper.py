@@ -73,11 +73,13 @@ class StepCarry(NamedTuple):
 def select_lanes(mask, new, old):
     """Per-lane carry select: lanes where ``mask`` is True take ``new``,
     the rest keep ``old`` (the explicit form of the freeze that vmap of
-    while_loop performs on finished lanes)."""
+    while_loop performs on finished lanes, under the same device scope,
+    ``gravfm.loop``)."""
     def sel(n, o):
         b = mask.reshape((mask.shape[0],) + (1,) * (n.ndim - 1))
         return jnp.where(b, n, o)
-    return jax.tree.map(sel, new, old)
+    with jax.named_scope("gravfm.loop"):
+        return jax.tree.map(sel, new, old)
 
 
 class SuperstepProgram:
@@ -103,28 +105,32 @@ class SuperstepProgram:
         self.global_any = global_any or (lambda b: b)
 
     # ------------------------------------------------------------------
+    # Every phase runs under a ``jax.named_scope`` (``gravfm.<phase>``:
+    # init, deliver, gather, stats, apply, cond, and loop for the loop's
+    # own lane selects; the shard engine adds exchange inside deliver):
+    # metadata only, so the compiled instructions are unchanged, but each
+    # fusion's ``op_name`` then names the phase it came from, and the
+    # service maps device ops in a profiler trace back to the phase.
+
     def init_carry(self, data, params: Dict[str, Any],
                    query_kwargs: Dict[str, Any]) -> StepCarry:
         k = self.kernel
-        state = k.init_state(data.vert_gid, data.out_deg, data.vert_valid,
-                             **{**params, **query_kwargs})
-        state, payload, active = k.apply(state, data.vert_gid,
-                                         data.out_deg, 0)
-        active = active & data.vert_valid
-        return StepCarry(state, payload, active, jnp.int32(0),
-                         self.init_stats())
-
-    # The superstep, split at the paper's pipeline-stage boundaries so a
-    # profiled stepper can host-time each piece (scatter ~ L_mem, combine
-    # + apply ~ L_PE/L_node, the deliver collective ~ L_if/L_net — see
-    # perfmodel.PHASE_TERMS). ``step`` composes them back into the exact
-    # pre-split op sequence, so the fused fast path traces identically.
+        with jax.named_scope("gravfm.init"):
+            state = k.init_state(data.vert_gid, data.out_deg,
+                                 data.vert_valid,
+                                 **{**params, **query_kwargs})
+            state, payload, active = k.apply(state, data.vert_gid,
+                                             data.out_deg, 0)
+            active = active & data.vert_valid
+            return StepCarry(state, payload, active, jnp.int32(0),
+                             self.init_stats())
 
     def step_deliver(self, data, carry: StepCarry):
-        """Scatter/exchange: move this superstep's pending updates to
-        their receivers. Returns the opaque delivered tuple
+        """Scatter/exchange, the edge pass: move this superstep's pending
+        updates to their receivers. Returns the opaque delivered tuple
         ``(acc, got, carry_vals, aux)`` that ``step_combine`` folds."""
-        return self.deliver(data, carry.payload, carry.active)
+        with jax.named_scope("gravfm.deliver"):
+            return self.deliver(data, carry.payload, carry.active)
 
     def step_combine(self, data, carry: StepCarry, delivered) -> StepCarry:
         """Gather-combine the delivered updates into vertex state and
@@ -133,36 +139,33 @@ class SuperstepProgram:
         k = self.kernel
         state, payload, active, s, stats = carry
         acc, got, carry_v, aux = delivered
-        if k.carry_dtype is not None:
-            state = k.gather(state, acc, carry_v, got, s)
-        else:
-            state = k.gather(state, acc, got, s)
-        stats = self.update_stats(stats, data, active, aux)
+        with jax.named_scope("gravfm.gather"):
+            if k.carry_dtype is not None:
+                state = k.gather(state, acc, carry_v, got, s)
+            else:
+                state = k.gather(state, acc, got, s)
+        with jax.named_scope("gravfm.stats"):
+            stats = self.update_stats(stats, data, active, aux)
         return StepCarry(state, payload, active, s, stats)
-
-    def step_exchange(self, data, carry: StepCarry) -> StepCarry:
-        """deliver + combine fused — the shard stepper's profiled unit
-        (inside shard_map the collective and the receiver-side fold
-        cannot be host-separated without materializing per-shard
-        intermediates)."""
-        return self.step_combine(data, carry,
-                                 self.step_deliver(data, carry))
 
     def step_apply(self, data, mid: StepCarry) -> StepCarry:
         """The vertex apply of the *next* superstep's updates: advances
         the superstep counter and re-masks activity."""
         k = self.kernel
         state, _, active, s, stats = mid
-        state, payload, active = k.apply(state, data.vert_gid,
-                                         data.out_deg, s + 1)
-        active = active & data.vert_valid
-        return StepCarry(state, payload, active, s + 1, stats)
+        with jax.named_scope("gravfm.apply"):
+            state, payload, active = k.apply(state, data.vert_gid,
+                                             data.out_deg, s + 1)
+            active = active & data.vert_valid
+            return StepCarry(state, payload, active, s + 1, stats)
 
     def step(self, data, carry: StepCarry) -> StepCarry:
-        return self.step_apply(data, self.step_exchange(data, carry))
+        return self.step_apply(data, self.step_combine(
+            data, carry, self.step_deliver(data, carry)))
 
     def alive(self, carry: StepCarry) -> jnp.ndarray:
-        return self.global_any(jnp.any(carry.active))
+        with jax.named_scope("gravfm.cond"):
+            return self.global_any(jnp.any(carry.active))
 
     def is_done(self, carry: StepCarry) -> jnp.ndarray:
         return ~self.alive(carry)
@@ -171,16 +174,20 @@ class SuperstepProgram:
     def while_run(self, data, cap, params: Dict[str, Any],
                   query_kwargs: Dict[str, Any]) -> StepCarry:
         """The fast path: run to quiescence (or ``cap``) in one
-        ``lax.while_loop`` over ``step``."""
+        ``lax.while_loop`` over ``step``. The loop itself runs under
+        ``gravfm.loop``, which names what vmap adds to a batched loop
+        (the per-lane freeze of finished lanes' carries)."""
         carry = self.init_carry(data, params, query_kwargs)
 
         def cond(c):
-            return self.alive(c) & (c.superstep < cap)
+            with jax.named_scope("gravfm.cond"):
+                return self.alive(c) & (c.superstep < cap)
 
         def body(c):
             return self.step(data, c)
 
-        return jax.lax.while_loop(cond, body, carry)
+        with jax.named_scope("gravfm.loop"):
+            return jax.lax.while_loop(cond, body, carry)
 
 
 class LaneStepperBase:
@@ -197,17 +204,6 @@ class LaneStepperBase:
     # LaneTable.step turns consecutive values into per-superstep deltas
     # for the trace bus.
     last_wire_words: float = 0.0
-
-    # Opt-in phase profiling: when True, ``step`` dispatches the
-    # superstep as separate phase programs with a ``block_until_ready``
-    # host-timing boundary between them and leaves the wall split in
-    # ``last_phases`` ({phase: seconds}); the default fused single
-    # dispatch is untouched and leaves it None. The phase select/masking
-    # is identical to the fused path, so results are bit-identical —
-    # only the dispatch granularity (and therefore XLA's fusion scope
-    # and the wall clock) changes.
-    profile: bool = False
-    last_phases: Optional[Dict[str, float]] = None
 
     def _unpack(self, out):
         carry = out[0]
@@ -315,23 +311,6 @@ class LaneStepper(LaneStepperBase):
             c = select_lanes(alive, new, carry)
             return (c, *probe_of(c))
 
-        # profiled-mode phase programs (traced only if profiling is ever
-        # turned on): the same superstep as step_fn, cut at the
-        # scatter / combine / apply boundaries so the host can time each
-        def deliver_fn(d, carry):
-            hook()
-            return jax.vmap(lambda c: prog.step_deliver(d, c))(carry)
-
-        def combine_fn(d, carry, delivered):
-            hook()
-            return jax.vmap(
-                lambda c, dv: prog.step_combine(d, c, dv))(carry, delivered)
-
-        def apply_fn(d, carry, mid, alive):
-            hook()
-            new = jax.vmap(lambda c: prog.step_apply(d, c))(mid)
-            return select_lanes(alive, new, carry)
-
         def fetch_lane_fn(carry, lane):
             hook()
             return jax.tree.map(
@@ -354,9 +333,6 @@ class LaneStepper(LaneStepperBase):
         self._probe = jax.jit(probe_of)
         self._fetch_lane = jax.jit(fetch_lane_fn)
         self._restore = jax.jit(restore_fn)
-        self._deliver_p = jax.jit(deliver_fn)
-        self._combine_p = jax.jit(combine_fn)
-        self._apply_p = jax.jit(apply_fn)
 
     def init(self, qkw: Dict[str, np.ndarray]):
         return self._unpack(self._init(self._data, self._qdev(qkw)))
@@ -368,43 +344,8 @@ class LaneStepper(LaneStepperBase):
                                         jnp.asarray(fresh)))
 
     def step(self, carry: StepCarry, alive: np.ndarray):
-        if not self.profile:
-            self.last_phases = None
-            return self._unpack(self._step(self._data, carry,
-                                           jnp.asarray(alive)))
-        return self._profiled_step(carry, alive)
-
-    def _profiled_step(self, carry: StepCarry, alive: np.ndarray):
-        """One superstep as four phase dispatches with host-timed
-        ``block_until_ready`` boundaries. Same ops and the same
-        select/masking as the fused path (bit-identical results); the
-        extra syncs are the profiling overhead, which is exactly what
-        makes the per-phase wall split measurable."""
-        d, alive_dev = self._data, jnp.asarray(alive)
-        phases: Dict[str, float] = {}
-        t = time.perf_counter()
-        delivered = self._deliver_p(d, carry)
-        jax.block_until_ready(delivered)
-        now = time.perf_counter()
-        phases["scatter"] = now - t
-        t = now
-        mid = self._combine_p(d, carry, delivered)
-        jax.block_until_ready(mid)
-        now = time.perf_counter()
-        phases["combine"] = now - t
-        t = now
-        new = self._apply_p(d, carry, mid, alive_dev)
-        jax.block_until_ready(new)
-        now = time.perf_counter()
-        phases["apply"] = now - t
-        t = now
-        out = self._probe(new)
-        act, steps = np.asarray(out[0]), np.asarray(out[1])
-        if len(out) > 2:
-            self.last_wire_words = float(np.asarray(out[2]))
-        phases["probe"] = time.perf_counter() - t
-        self.last_phases = phases
-        return new, act, steps
+        return self._unpack(self._step(self._data, carry,
+                                       jnp.asarray(alive)))
 
 
 # ---------------------------------------------------------------------------
@@ -623,12 +564,6 @@ class LaneTable:
         # here bounds the full dispatch+sync, not just the enqueue
         w1 = getattr(self.stepper, "last_wire_words", 0.0)
         extra = {}
-        ph = getattr(self.stepper, "last_phases", None)
-        if ph is not None:
-            # profiled mode: the measured scatter/combine/apply/probe
-            # wall split rides the event (Perfetto args pane / L_* term
-            # comparison against perfmodel.phase_projection)
-            extra["phase"] = dict(ph)
         if self.devices:
             # per-device attribution: the mesh devices this dispatch
             # fanned out to (single-device tables omit the column)

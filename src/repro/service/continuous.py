@@ -227,7 +227,7 @@ class ContinuousScheduler:
                  park_release: Callable[[int], None] = None,
                  depth_bucket_of: Callable[
                      [QueryClass, QueryRequest], Optional[str]] = None,
-                 trace=None, metrics=None, profile: bool = False):
+                 trace=None, metrics=None):
         assert slots >= 1
         self.slots = slots
         self.max_supersteps = max_supersteps
@@ -235,11 +235,8 @@ class ContinuousScheduler:
         # duck-typed event bus (service.trace.TraceBus); None = no tracing
         self.trace = trace
         # duck-typed metrics registry (service.metrics.MetricsRegistry);
-        # None = no per-class phase histograms
+        # None = no per-device superstep counters
         self.metrics = metrics
-        # when True every class's stepper runs in profiled mode (phase
-        # wall split on superstep events + phase histograms)
-        self.profile = profile
         self.preemption = preemption
         self.aging_rate = aging_rate
         self.depth_bucket_s = depth_bucket_s
@@ -304,11 +301,6 @@ class ContinuousScheduler:
                                            self._park_release),
                                trace=self.trace,
                                label=class_key(qclass))
-                # profiled mode is a stepper-level switch: flip it when
-                # the class's stepper enters service (steppers are
-                # engine-cached per width, so a re-created class run
-                # keeps the mode consistent)
-                splan.stepper.profile = self.profile
                 self._classes[qclass] = cr
             q = cr.queues.get(req.tenant)
             if q is None:
@@ -479,26 +471,6 @@ class ContinuousScheduler:
                 self.stats.record_compile(wall)
         if eng.traces == traces0:
             ck = class_key(qclass)
-            # profiled mode: per-class phase histograms + exchange
-            # overlap accounting (compile walls excluded for the same
-            # reason as above)
-            phases = getattr(cr.splan.stepper, "last_phases", None)
-            if phases:
-                if self.stats is not None and "exchange" in phases:
-                    # exposed = the serving schedule's exchange wall;
-                    # total = the serial-reference wall (profiled
-                    # overlapped steppers time both; synchronous ones
-                    # have no reference, so exposed == total -> 1.0)
-                    self.stats.record_exchange_overlap(
-                        ck, phases["exchange"],
-                        phases.get("exchange_serial", phases["exchange"]))
-                if self.metrics is not None:
-                    for phase, secs in phases.items():
-                        self.metrics.observe(
-                            "gravfm_superstep_phase_seconds", secs,
-                            help="Measured superstep wall split by phase "
-                                 "(profiled mode)",
-                            **{"class": ck, "phase": phase})
             if self.metrics is not None and cr.devices:
                 # per-device attribution: every mesh device ran this
                 # superstep's shard_map dispatch

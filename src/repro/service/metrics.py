@@ -315,6 +315,8 @@ _SNAP_GAUGES = {
     "latency_p99_ms": "gravfm_latency_p99_ms",
     "queue_wait_p50_ms": "gravfm_queue_wait_p50_ms",
     "queue_wait_p95_ms": "gravfm_queue_wait_p95_ms",
+    "device_wait_p50_ms": "gravfm_device_wait_p50_ms",
+    "device_wait_p95_ms": "gravfm_device_wait_p95_ms",
     "depth_pred_abs_err": "gravfm_depth_pred_abs_err",
     "pending": "gravfm_pending_queries",
     "parked_lanes": "gravfm_parked_lanes",
@@ -368,13 +370,6 @@ def feed_service_snapshot(reg: MetricsRegistry, snap: Dict[str, Any],
                         **{"class": ck})
         reg.set_gauge("gravfm_class_words_per_message",
                       r["words_per_message"], **{"class": ck})
-        if r.get("overlap_efficiency") is not None:
-            # exposed/total exchange wall (profiled shard classes):
-            # 1.0 = synchronous, -> 0 = exchange fully hidden
-            reg.set_gauge("gravfm_overlap_efficiency",
-                          float(r["overlap_efficiency"]),
-                          help="Exposed / total exchange time per class",
-                          **{"class": ck})
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,7 @@ from ..core.partition import PartitionedGraph
 from ..core.stepper import LaneStepper
 from ..store import GraphStore
 from .stats import ServiceStats
+from .trace import TraceBus, hlo_op_scopes
 
 __all__ = ["PlanKey", "CompiledPlan", "PlanCache", "StepperPlan"]
 
@@ -84,6 +85,11 @@ class CompiledPlan:
         self.key = key
         self.engine = engine
         self.executions = 0
+        # set by compile(): the XLA module the plan dispatches, and the
+        # device scope (gravfm.deliver, ...; "" for none) of each of its
+        # ops, read from the compiled HLO's op_name metadata
+        self.program: Optional[str] = None
+        self.op_scopes: Dict[str, str] = {}
 
     @property
     def query_params(self) -> Tuple[str, ...]:
@@ -111,9 +117,20 @@ class CompiledPlan:
                     f"for {k!r}")
         return self.engine.run_batch(max_supersteps, **ov, **query_arrays)
 
+    def compile(self) -> "CompiledPlan":
+        """Trace and compile (or load from JAX's persistent cache) the
+        program :meth:`execute` dispatches, and read its op-to-scope map.
+        The dispatch shares the trace and compile caches, so it compiles
+        nothing more."""
+        if self.program is None:
+            ov = {"overlap": True} if self.key.overlap else {}
+            compiled = self.engine.lower(self.key.batch_size, **ov).compile()
+            self.program, self.op_scopes = hlo_op_scopes(compiled.as_text())
+        return self
+
     def warmup(self) -> "CompiledPlan":
-        """Trace + compile now (first root of the graph) so the first real
-        query pays dispatch cost only."""
+        """Compile, then execute once (first root of the graph) so the
+        first real query pays dispatch cost only."""
         if self.query_params:
             dummy = {p: np.zeros((self.key.batch_size,), np.int32)
                      for p in self.query_params}
@@ -123,6 +140,7 @@ class CompiledPlan:
             raise ValueError(
                 f"kernel {self.key.kernel!r} has no query_params; "
                 "only batch_size=1 plans are meaningful")
+        self.compile()
         self.execute(**dummy)
         return self
 
@@ -153,6 +171,9 @@ class PlanCache:
     def __init__(self, stats: Optional[ServiceStats] = None,
                  store: Optional[GraphStore] = None):
         self.stats = stats or ServiceStats()
+        # set-up spans (engine_build / compile / warm_run) land here;
+        # disabled until the service attaches its bus (set_trace)
+        self.trace = TraceBus(capacity=1, enabled=False)
         self.store = store or GraphStore()
         self.store.add_evict_listener(self.invalidate_graph)
         self.store.add_spill_listener(self.offload_graph)
@@ -174,6 +195,13 @@ class PlanCache:
                                   int] = {}
         self._plans: Dict[PlanKey, CompiledPlan] = {}
         self._steppers: Dict[PlanKey, StepperPlan] = {}
+
+    def set_trace(self, bus: TraceBus) -> None:
+        """Attach the service's event bus: set-up spans, and the engines'
+        execute/fetch/collect spans, land on it."""
+        self.trace = bus
+        for eng in list(self._engines.values()):
+            eng.trace = bus
 
     # ---------------- graphs ------------------------------------------
     def register_graph(self, graph_id: str, graph: Graph, *,
@@ -230,16 +258,19 @@ class PlanCache:
                                f"{sorted(ALGORITHMS)}")
             pg = self.graph(key.graph_id, key.num_shards, method,
                             version=key.version or None)
-            if key.exchange:
-                from ..core.engine_shardmap import ShardEngine
-                from ..launch.mesh import make_serving_mesh
-                mesh = make_serving_mesh(key.num_shards)
-                eng = ShardEngine(ALGORITHMS[key.kernel](), pg, mesh=mesh,
-                                  exchange=key.exchange,
-                                  backend=key.backend)
-            else:
-                eng = Engine(ALGORITHMS[key.kernel](), pg, mode=key.mode,
-                             backend=key.backend)
+            with self.trace.span("engine_build", graph_id=key.graph_id,
+                                 version=key.version, kernel=key.kernel):
+                if key.exchange:
+                    from ..core.engine_shardmap import ShardEngine
+                    from ..launch.mesh import make_serving_mesh
+                    mesh = make_serving_mesh(key.num_shards)
+                    eng = ShardEngine(ALGORITHMS[key.kernel](), pg,
+                                      mesh=mesh, exchange=key.exchange,
+                                      backend=key.backend)
+                else:
+                    eng = Engine(ALGORITHMS[key.kernel](), pg,
+                                 mode=key.mode, backend=key.backend)
+            eng.trace = self.trace
             self._engines[ek] = eng
             # charge the TRUE engine-tier device bytes against the
             # store's budget (replacing the partition-layout proxy): a
@@ -265,9 +296,26 @@ class PlanCache:
                     "it cannot be query-batched (batch_size must be 1)")
             plan = CompiledPlan(key, engine)
             if warm:
-                plan.warmup()
+                fields = dict(graph_id=key.graph_id, version=key.version,
+                              kernel=key.kernel, batch_size=key.batch_size)
+                with self.trace.span("compile", **fields):
+                    plan.compile()
+                with self.trace.span("warm_run", **fields):
+                    plan.warmup()
             self._plans[key] = plan
         return plan
+
+    def op_scopes(self) -> Dict[Tuple[str, str], str]:
+        """``{(XLA module, op name): device scope}`` over every compiled
+        plan: ties a profiler trace's device ops (named on its "XLA Ops"
+        line, their program on its "XLA Modules" line) to the superstep
+        phase (``gravfm.deliver``, ...) they belong to; "" for an op
+        under no scope."""
+        out: Dict[Tuple[str, str], str] = {}
+        for plan in list(self._plans.values()):
+            for op, scope in plan.op_scopes.items():
+                out[(plan.program, op)] = scope
+        return out
 
     def get_stepper(self, key: PlanKey, *,
                     method: str = "greedy") -> StepperPlan:

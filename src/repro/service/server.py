@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 from concurrent.futures import Future
@@ -131,8 +132,7 @@ class GraphQueryService:
                  roofline_platform=None,
                  metrics: bool = True,
                  watchdog: bool = False,
-                 watchdog_config: Optional[WatchdogConfig] = None,
-                 profile_phases: bool = False):
+                 watchdog_config: Optional[WatchdogConfig] = None):
         assert scheduling in ("bucketed", "continuous")
         self.num_shards = num_shards
         self.max_batch = max_batch
@@ -170,7 +170,6 @@ class GraphQueryService:
         # gauges at scrape, so serving pays nothing per query.
         self.metrics = MetricsRegistry(enabled=metrics)
         self.metrics.add_collector(self._collect_metrics)
-        self.profile_phases = profile_phases
         self._watchdog: Optional[Watchdog] = None
         self._watchdog_on = watchdog
         self._watchdog_config = watchdog_config
@@ -214,8 +213,7 @@ class GraphQueryService:
                 park_charge=self.store.reserve_parked,
                 park_release=self.store.release_parked,
                 depth_bucket_of=self._depth_bucket_of,
-                trace=self.trace, metrics=self.metrics,
-                profile=profile_phases)
+                trace=self.trace, metrics=self.metrics)
         # Result cache PARTITIONED BY TENANT: each tenant gets its own
         # bounded LRU of ``result_cache_size`` entries, so one tenant's
         # burst of novel queries cannot evict another tenant's hot
@@ -238,6 +236,9 @@ class GraphQueryService:
         # events, so a trace shows "this query's restore stalled on that
         # graph's refault" on one timeline
         self.store.set_trace(self.trace)
+        # set-up spans (engine build, compile, warm-up) and the engines'
+        # execute/fetch/collect spans land on the same bus
+        self.plans.set_trace(self.trace)
         # roofline telemetry: class key -> the §5 performance model's
         # projected TEPS (T_sys). The projector runs outside the stats
         # lock and is cached per class (limits() is pure arithmetic but
@@ -259,6 +260,7 @@ class GraphQueryService:
         # locked (its contract is "callers serialize dispatch"), and a
         # full-batch submit() can race the scheduler thread's poll().
         self._dispatch_lock = threading.Lock()  # lock: dispatch
+        self._batch_seq = itertools.count(1)    # numbers dispatched batches
         self._thread: Optional[threading.Thread] = None
         self._running = False
 
@@ -316,9 +318,6 @@ class GraphQueryService:
                 version, exchange, overlap))
             qkw = {p: np.zeros((self._slots,), np.int32)
                    for p in splan.query_params}
-            # profiled serving dispatches the phase programs instead of
-            # the fused step — warm whichever path will actually run
-            splan.stepper.profile = self.profile_phases
             carry, _, _ = splan.stepper.init(qkw)
             carry, _, _ = splan.stepper.admit(
                 carry, qkw, np.zeros(self._slots, bool))
@@ -659,9 +658,7 @@ class GraphQueryService:
         return lim
 
     def projected_limits(self, ck: str) -> Optional[Dict[str, float]]:
-        """Public per-term model projection for one class key; combine
-        with :func:`~repro.core.perfmodel.phase_projection` to set a
-        profiled phase split against the model term by term."""
+        """Public per-term model projection for one class key."""
         return self._project_limits(ck)
 
     def _project_teps(self, ck: str) -> Optional[float]:
@@ -683,6 +680,14 @@ class GraphQueryService:
         load the file in ``chrome://tracing`` or
         https://ui.perfetto.dev. Returns ``path``."""
         return self.trace.dump(path)
+
+    def op_scopes(self) -> Dict[Any, str]:
+        """``{(XLA module, op name): device scope}`` for every compiled
+        plan (see :meth:`PlanCache.op_scopes`): which superstep phase
+        (``gravfm.deliver``, ``gravfm.apply``, ...) each device op of a
+        ``jax.profiler`` trace belongs to."""
+        with self._dispatch_lock:
+            return self.plans.op_scopes()
 
     def query(self, graph_id: str, kernel: str, *, mode: str = "gravfm",
               deadline_ms: float = 50.0, tenant: str = "default",
@@ -726,19 +731,32 @@ class GraphQueryService:
         futs = [it[1] for it in live]
         n = len(reqs)
         t0 = time.perf_counter()
-        with self._dispatch_lock:
-            self._dispatch_locked(qclass, reqs, futs, n, t0)
+        ck = class_key(qclass)
+        # every span of this batch, the engine's included, names it
+        with self.trace.context(klass=ck, qids=[r.qid for r in reqs],
+                                batch_size=n, batch=next(self._batch_seq)):
+            # admit -> launch: the formed batch waits for the device
+            # (the batch ahead of it holds the dispatch lock)
+            wait = self.trace.span("device_wait", ts=t0)
+            with self._dispatch_lock:
+                t_launch = time.perf_counter()
+                wait.end(t_launch)
+                self._dispatch_locked(qclass, reqs, futs, n, t0, t_launch)
 
     def _dispatch_locked(self, qclass: QueryClass, reqs, futs, n: int,
-                         t0: float) -> None:
+                         t0: float, t_launch: float) -> None:
         ck = class_key(qclass)
         for r in reqs:
             self.trace.emit("admit", qid=r.qid, tenant=r.tenant,
                             klass=ck, reason="batch", ts=t0,
                             batch_size=n)
-            # submit->dispatch wait (the SLO watchdog's queue_wait_p95
-            # rule; the continuous path records at lane admission)
+            self.trace.emit("launch", qid=r.qid, tenant=r.tenant,
+                            klass=ck, ts=t_launch)
+            # submit->dispatch wait, batch formation only (the SLO
+            # watchdog's queue_wait_p95 rule; the continuous path
+            # records at lane admission), then the wait for the device
             self.stats.record_queue_wait((t0 - r.arrival_s) * 1e3)
+            self.stats.record_device_wait((t_launch - t0) * 1e3)
         traces_before = self.plans.sync_trace_counters()
         lease = None
         try:
@@ -776,52 +794,55 @@ class GraphQueryService:
         finally:
             if lease is not None:
                 lease.release()
-        now = time.perf_counter()
-        wall = now - t0
-        for f, res in zip(futs, results):
-            f.set_result(res)
-        traces_after = self.plans.sync_trace_counters()
-        compiled = traces_after != traces_before
-        self.stats.record_batch(
-            n_queries=n, n_pad=max(0, bucket - n) if bucket > 1 else 0,
-            # a traced dispatch's wall is compile-dominated: account it
-            # to compile_time_s so busy_time_s (the qps_busy/TEPS
-            # denominator) stays execution-only, matching the
-            # continuous pump's accounting
-            wall_s=0.0 if compiled else wall,
-            messages=sum(r.messages for r in results),
-            supersteps=max((r.supersteps for r in results), default=0),
-            latencies_ms=[(now - r.arrival_s) * 1e3 for r in reqs],
-            class_key=ck,
-            wire_words=sum(float(r.comm.get("wire_words", 0.0))
-                           for r in results))
-        if compiled:
-            self.stats.record_compile(wall)
-        # feed the admission-control cost model + the result cache;
-        # dispatches that traced (compiled) are excluded from the cost
-        # model — a compile wall would poison the EWMA and, with
-        # admission control on, shed the class forever
-        batch_depth = max((r.supersteps for r in results), default=0)
-        if batch_depth > 0 and not compiled:
-            self.stats.record_superstep_time(ck, wall, n_steps=batch_depth)
-        for r, res in zip(reqs, results):
-            self.stats.record_query_depth(ck, res.supersteps)
-            slack_s = r.deadline_s - now
-            missed = slack_s < 0
-            if missed:
-                self.stats.record_deadline_miss()
-            self.stats.record_tenant(
-                r.tenant, completed=1, messages=res.messages,
-                latency_ms=(now - r.arrival_s) * 1e3,
-                deadline_misses=1 if missed else 0)
-            self.trace.emit(
-                "retire", qid=r.qid, tenant=r.tenant, klass=ck,
-                reason="retired", supersteps=int(res.supersteps),
-                messages=int(res.messages),
-                deadline_slack_s=(slack_s if np.isfinite(slack_s)
-                                  else None),
-                ts=now)
-            self._store_result(r, res, qclass.version)
+        # futures, stats, retire events and the result cache: host time
+        # for which this batch keeps the next one off the device
+        with self.trace.span("resolve"):
+            now = time.perf_counter()
+            wall = now - t0
+            for f, res in zip(futs, results):
+                f.set_result(res)
+            traces_after = self.plans.sync_trace_counters()
+            compiled = traces_after != traces_before
+            self.stats.record_batch(
+                n_queries=n, n_pad=max(0, bucket - n) if bucket > 1 else 0,
+                # a traced dispatch's wall is compile-dominated: account it
+                # to compile_time_s so busy_time_s (the qps_busy/TEPS
+                # denominator) stays execution-only, matching the
+                # continuous pump's accounting
+                wall_s=0.0 if compiled else wall,
+                messages=sum(r.messages for r in results),
+                supersteps=max((r.supersteps for r in results), default=0),
+                latencies_ms=[(now - r.arrival_s) * 1e3 for r in reqs],
+                class_key=ck,
+                wire_words=sum(float(r.comm.get("wire_words", 0.0))
+                               for r in results))
+            if compiled:
+                self.stats.record_compile(wall)
+            # feed the admission-control cost model + the result cache;
+            # dispatches that traced (compiled) are excluded from the cost
+            # model — a compile wall would poison the EWMA and, with
+            # admission control on, shed the class forever
+            batch_depth = max((r.supersteps for r in results), default=0)
+            if batch_depth > 0 and not compiled:
+                self.stats.record_superstep_time(ck, wall, n_steps=batch_depth)
+            for r, res in zip(reqs, results):
+                self.stats.record_query_depth(ck, res.supersteps)
+                slack_s = r.deadline_s - now
+                missed = slack_s < 0
+                if missed:
+                    self.stats.record_deadline_miss()
+                self.stats.record_tenant(
+                    r.tenant, completed=1, messages=res.messages,
+                    latency_ms=(now - r.arrival_s) * 1e3,
+                    deadline_misses=1 if missed else 0)
+                self.trace.emit(
+                    "retire", qid=r.qid, tenant=r.tenant, klass=ck,
+                    reason="retired", supersteps=int(res.supersteps),
+                    messages=int(res.messages),
+                    deadline_slack_s=(slack_s if np.isfinite(slack_s)
+                                      else None),
+                    ts=now)
+                self._store_result(r, res, qclass.version)
 
     # ---------------- scheduling --------------------------------------
     def poll(self, now_s: Optional[float] = None) -> int:

@@ -75,6 +75,10 @@ class ServiceStats:
         # queue-wait (submit -> lane/batch admission) window: the SLO
         # watchdog's queue_wait_p95 rule reads these percentiles
         self._queue_waits_ms = collections.deque(maxlen=self.latency_window)
+        # admit -> launch window (bucketed batches): a formed batch's wait
+        # for the dispatch lock, i.e. for the batch ahead of it to finish
+        self._device_waits_ms = collections.deque(
+            maxlen=self.latency_window)
         self._started_at = time.perf_counter()
         # per-tenant breakdown (submitted/completed/shed/messages and a
         # bounded latency window) for the multi-tenant stats endpoint
@@ -114,12 +118,7 @@ class ServiceStats:
         if acc is None:
             acc = self._class_acc[class_key] = {
                 "messages": 0.0, "busy_s": 0.0, "completed": 0.0,
-                "wire_words": 0.0,
-                # exchange overlap accounting (profiled shard steppers):
-                # exposed = wall the exchange actually spent on the
-                # critical path under the serving schedule; total = the
-                # same superstep's serial-reference exchange wall
-                "exposed_exchange_s": 0.0, "total_exchange_s": 0.0}
+                "wire_words": 0.0}
         return acc
 
     def record_batch(self, n_queries: int, n_pad: int, wall_s: float,
@@ -172,6 +171,12 @@ class ServiceStats:
         leaves a queue for a lane or a dispatched batch)."""
         with self._lock:
             self._queue_waits_ms.append(wait_ms)
+
+    def record_device_wait(self, wait_ms: float) -> None:
+        """One query's admit->launch wait: its formed batch waiting for
+        the device while the batch ahead of it runs (bucketed path)."""
+        with self._lock:
+            self._device_waits_ms.append(wait_ms)
 
     # ---- per-tenant breakdown -----------------------------------------
     def _tenant(self, tenant: str) -> Dict[str, float]:
@@ -322,18 +327,6 @@ class ServiceStats:
                 acc["completed"] += 1
                 acc["wire_words"] += wire_words
 
-    def record_exchange_overlap(self, class_key: str, exposed_s: float,
-                                total_s: float) -> None:
-        """One profiled superstep's exchange walls: ``exposed_s`` is
-        what the serving schedule actually paid on the critical path,
-        ``total_s`` the serial-reference exchange wall for the same
-        superstep. Synchronous schedules record exposed == total; the
-        ratio surfaces as per-class ``overlap_efficiency``."""
-        with self._lock:
-            acc = self._class_acc_of(class_key)
-            acc["exposed_exchange_s"] += float(exposed_s)
-            acc["total_exchange_s"] += float(total_s)
-
     def record_deadline_miss(self, n: int = 1) -> None:
         """A query completed AFTER its deadline (counted where the
         engine resolves it — bucketed dispatch and continuous retire;
@@ -378,13 +371,6 @@ class ServiceStats:
                 "words_per_message": (ww / a["messages"]
                                       if a["messages"] > 0 else 0.0),
             }
-            te = a.get("total_exchange_s", 0.0)
-            # exposed/total exchange wall: 1.0 = fully synchronous (the
-            # exchange is entirely on the critical path), -> 0 = fully
-            # hidden behind local compute. None until a profiled
-            # superstep has fed the accumulators.
-            out[ck]["overlap_efficiency"] = (
-                a.get("exposed_exchange_s", 0.0) / te if te > 0 else None)
         return out
 
     # ------------------------------------------------------------------
@@ -393,6 +379,7 @@ class ServiceStats:
         with self._lock:
             lat = list(self._latencies_ms)
             qwait = list(self._queue_waits_ms)
+            dwait = list(self._device_waits_ms)
             elapsed = max(time.perf_counter() - self._started_at, 1e-9)
             # before any dispatch has run, busy_time_s is exactly 0 and
             # qps_busy/teps must report 0.0 — the old 1e-9 clamp leaked
@@ -438,6 +425,8 @@ class ServiceStats:
                 "latency_max_ms": percentile(lat, 100),
                 "queue_wait_p50_ms": percentile(qwait, 50),
                 "queue_wait_p95_ms": percentile(qwait, 95),
+                "device_wait_p50_ms": percentile(dwait, 50),
+                "device_wait_p95_ms": percentile(dwait, 95),
                 "uptime_s": elapsed,
             }
         # outside the stats lock: the roofline projector may take the

@@ -13,6 +13,9 @@ shared, thread-safe, bounded :class:`TraceBus`:
   queue       server/scheduler — request entered a scheduler queue
   admit       scheduler — request took a lane / joined a dispatched
               batch (``reason``: fresh | preempt | batch)
+  launch      server — the request's batch took the device (the
+              dispatch lock); ``admit`` → ``launch`` is its wait for
+              the device
   superstep   LaneTable (core/stepper.py) — one fused device dispatch,
               with wall time and the lane→query attribution map
   park        scheduler — an active lane was checkpointed to host
@@ -33,6 +36,31 @@ shared, thread-safe, bounded :class:`TraceBus`:
               ``threshold``; ``klass`` carries the subject)
   ==========  =======================================================
 
+Interval spans (:meth:`TraceBus.span`) record one event each, with
+``ts`` and ``dur_s``, and hold a ``jax.profiler.TraceAnnotation``
+named ``gravfm.<kind>`` while open, so a ``jax.profiler`` capture shows
+them on the host plane on the device ops' clock:
+
+  ============  =====================================================
+  span          covers
+  ============  =====================================================
+  device_wait   server — a formed batch waiting for the dispatch lock
+                (``admit`` → ``launch``)
+  execute       engine — program enqueued → outputs ready on device
+  fetch         engine — outputs copied to the host
+  collect       engine — host arrays → per-query results
+  resolve       server — futures, stats, ``retire`` events and the
+                result cache, up to the lock's release
+  partition     store — ``partition_graph`` of a published version
+  engine_build  plan cache — an engine's host layout and its upload
+  compile       plan cache — trace + compile (or cache load) of a plan
+  warm_run      plan cache — a plan's warm-up execution
+  ============  =====================================================
+
+Spans carry the class key, batch size, qids and batch number of the
+batch they serve where they have them (:meth:`TraceBus.context`), and
+the name of the thread that ran them.
+
 The bus is a ring buffer: a long-running service keeps the most recent
 ``capacity`` events and counts what it dropped — tracing never grows
 without bound and never blocks a hot path (one leaf-lock append per
@@ -49,19 +77,36 @@ residency transitions as Chrome trace-event JSON
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import json
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["TraceEvent", "TraceBus", "QuerySpan", "EVENT_KINDS",
-           "assemble_spans", "chrome_trace"]
+import jax
+
+__all__ = ["TraceEvent", "TraceBus", "Span", "QuerySpan", "EVENT_KINDS",
+           "SPAN_KINDS", "SCOPE_PREFIX", "assemble_spans", "chrome_trace",
+           "hlo_op_scopes"]
 
 EVENT_KINDS = frozenset({
-    "submit", "queue", "admit", "superstep", "park", "restore", "retire",
-    "shed", "publish", "spill", "refault", "evict", "alert",
+    "submit", "queue", "admit", "launch", "superstep", "park", "restore",
+    "retire", "shed", "publish", "spill", "refault", "evict", "alert",
+    "device_wait", "execute", "fetch", "collect", "resolve",
+    "partition", "engine_build", "compile", "warm_run",
 })
+
+# the kinds :meth:`TraceBus.span` records (intervals, with dur_s)
+SPAN_KINDS = frozenset({
+    "device_wait", "execute", "fetch", "collect", "resolve",
+    "partition", "engine_build", "compile", "warm_run",
+})
+
+# prefix of every span's profiler annotation and of every device scope
+# (``jax.named_scope``) the superstep program opens
+SCOPE_PREFIX = "gravfm."
 
 
 @dataclasses.dataclass
@@ -69,9 +114,9 @@ class TraceEvent:
     """One lifecycle event. ``ts`` is ``time.perf_counter()`` seconds
     (the same clock every deadline and latency in the service uses);
     ``dur_s`` is nonzero only for events that cover an interval
-    (superstep dispatches). ``qid``/``tenant``/``klass`` attribute the
-    event to a query / tenant / query class; store events leave them
-    None and carry ``graph_id``/``version`` in ``attrs``."""
+    (superstep dispatches and spans). ``qid``/``tenant``/``klass``
+    attribute the event to a query / tenant / query class; store events
+    leave them None and carry ``graph_id``/``version`` in ``attrs``."""
 
     kind: str
     ts: float
@@ -80,6 +125,56 @@ class TraceEvent:
     klass: Optional[str] = None
     dur_s: float = 0.0
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+class Span:
+    """One open interval of a :class:`TraceBus`: holds the profiler
+    annotation ``gravfm.<kind>`` from construction to :meth:`end`, then
+    records one event with ``ts`` and ``dur_s``. A context manager, or
+    a begin/end pair when the interval does not nest in one block."""
+
+    __slots__ = ("_bus", "kind", "ts", "_fields", "_ann")
+
+    def __init__(self, bus: "TraceBus", kind: str, ts: float,
+                 fields: Dict[str, Any]):
+        self._bus, self.kind, self.ts, self._fields = bus, kind, ts, fields
+        self._ann = jax.profiler.TraceAnnotation(SCOPE_PREFIX + kind)
+        self._ann.__enter__()
+
+    def end(self, ts: Optional[float] = None) -> None:
+        """Close the span at ``ts`` (default now); later calls do
+        nothing. Call it on the thread that opened it."""
+        if self._ann is None:
+            return
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        f = self._fields
+        self._bus._append(TraceEvent(
+            kind=self.kind, ts=self.ts,
+            dur_s=(time.perf_counter() if ts is None else ts) - self.ts,
+            klass=f.pop("klass", None), attrs=f))
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+class _NullSpan:
+    """What a disabled bus hands out: every call is a no-op."""
+
+    def end(self, ts: Optional[float] = None) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class TraceBus:
@@ -99,6 +194,7 @@ class TraceBus:
         self._events: "collections.deque[TraceEvent]" = collections.deque(
             maxlen=capacity)
         self.emitted = 0        # total ever emitted (ring may have dropped)
+        self._local = threading.local()   # per-thread span context
 
     # ------------------------------------------------------------------
     def emit(self, kind: str, *, qid: Optional[int] = None,
@@ -108,13 +204,45 @@ class TraceBus:
         if not self.enabled:
             return
         assert kind in EVENT_KINDS, f"unknown trace event kind {kind!r}"
-        ev = TraceEvent(kind=kind,
-                        ts=time.perf_counter() if ts is None else ts,
-                        qid=qid, tenant=tenant, klass=klass,
-                        dur_s=dur_s, attrs=attrs)
+        self._append(TraceEvent(kind=kind,
+                                ts=time.perf_counter() if ts is None else ts,
+                                qid=qid, tenant=tenant, klass=klass,
+                                dur_s=dur_s, attrs=attrs))
+
+    def _append(self, ev: TraceEvent) -> None:
         with self._lock:
             self._events.append(ev)
             self.emitted += 1
+
+    def span(self, kind: str, *, ts: Optional[float] = None,
+             **fields) -> "Span":
+        """Open an interval span of ``kind`` (one of :data:`SPAN_KINDS`)
+        starting at ``ts`` (default now). The event carries ``fields``,
+        the thread's :meth:`context` and the thread's name. A disabled
+        bus returns a shared no-op span after one flag read."""
+        if not self.enabled:
+            return _NULL_SPAN
+        assert kind in SPAN_KINDS, f"unknown span kind {kind!r}"
+        ctx = getattr(self._local, "fields", None)
+        fields = {**ctx, **fields} if ctx else fields
+        fields["thread"] = threading.current_thread().name
+        return Span(self, kind, time.perf_counter() if ts is None else ts,
+                    fields)
+
+    @contextlib.contextmanager
+    def context(self, **fields):
+        """Fields (class key, qids, batch size, batch number) that every
+        span this thread opens inside the block carries — so the engine
+        layer's spans name the batch they serve without knowing it."""
+        if not self.enabled:
+            yield
+            return
+        prev = getattr(self._local, "fields", None)
+        self._local.fields = {**(prev or {}), **fields}
+        try:
+            yield
+        finally:
+            self._local.fields = prev
 
     @property
     def dropped(self) -> int:
@@ -162,14 +290,18 @@ class QuerySpan:
 
     Interval ends are ``None`` while the phase is still open at
     snapshot time (a query mid-flight has an open ``active`` interval).
-    ``outcome`` is None (in flight), ``"retired"``, ``"cache_hit"``,
-    ``"shed"``, or ``"error"``."""
+    ``queued`` is batch formation (``submit`` → ``admit``);
+    ``device_wait`` (bucketed batches only) is the formed batch's wait
+    for the device (``admit`` → ``launch``), after which ``active``
+    starts. ``outcome`` is None (in flight), ``"retired"``,
+    ``"cache_hit"``, ``"shed"``, or ``"error"``."""
 
     qid: int
     tenant: Optional[str] = None
     klass: Optional[str] = None
     submitted_s: Optional[float] = None
     queued: Optional[Tuple[float, Optional[float]]] = None
+    device_wait: Optional[Tuple[float, float]] = None
     active: List[Tuple[float, Optional[float]]] = \
         dataclasses.field(default_factory=list)
     parked: List[Tuple[float, Optional[float]]] = \
@@ -186,6 +318,11 @@ class QuerySpan:
         if self.queued is None or self.queued[1] is None:
             return 0.0
         return self.queued[1] - self.queued[0]
+
+    def device_wait_s(self) -> float:
+        if self.device_wait is None:
+            return 0.0
+        return self.device_wait[1] - self.device_wait[0]
 
     def active_s(self) -> float:
         return sum(b - a for a, b in self.active if b is not None)
@@ -230,6 +367,10 @@ def assemble_spans(events: List[TraceEvent]) -> Dict[int, QuerySpan]:
             elif sp.queued is None:     # submit/queue fell off the ring
                 sp.queued = (ev.ts, ev.ts)
             sp.active.append((ev.ts, None))
+        elif ev.kind == "launch":
+            if sp.active and sp.active[-1][1] is None:
+                sp.device_wait = (sp.active[-1][0], ev.ts)
+                sp.active[-1] = (ev.ts, None)
         elif ev.kind == "park":
             _close(sp.active, ev.ts)
             sp.parked.append((ev.ts, None))
@@ -287,12 +428,14 @@ def _json_safe(v):
 def chrome_trace(events: List[TraceEvent]) -> Dict[str, Any]:
     """Render events as Chrome trace-event JSON (Perfetto-loadable).
 
-    Layout: process 1 holds one thread per query (its queued / active /
-    parked phases as complete "X" slices, shed/retire reasons in args);
-    process 2 one thread per query class (the per-superstep device
-    dispatches, each with its lane→query attribution); process 3 the
-    graph store's residency transitions as instant events. Timestamps
-    are µs relative to the earliest retained event."""
+    Layout: process 1 holds one thread per query (its queued /
+    device_wait / active / parked phases as complete "X" slices,
+    shed/retire reasons in args); process 2 one thread per query class
+    (the per-superstep device dispatches, each with its lane→query
+    attribution) and one per service thread (its interval spans);
+    process 3 the graph store's residency transitions as instant
+    events. Timestamps are µs relative to the earliest retained
+    event."""
     out: List[Dict[str, Any]] = []
     if not events:
         return {"traceEvents": out, "displayTimeUnit": "ms"}
@@ -320,6 +463,8 @@ def chrome_trace(events: List[TraceEvent]) -> Dict[str, Any]:
         phases = []
         if sp.queued is not None:
             phases.append(("queued", [sp.queued]))
+        if sp.device_wait is not None:
+            phases.append(("device_wait", [sp.device_wait]))
         phases.append(("active", sp.active))
         phases.append(("parked", sp.parked))
         for name, intervals in phases:
@@ -346,21 +491,98 @@ def chrome_trace(events: List[TraceEvent]) -> Dict[str, Any]:
 
     # ---- scheduler dispatches + store transitions --------------------
     class_tids: Dict[str, int] = {}
+
+    def sched_tid(key: str) -> int:
+        tid = class_tids.get(key)
+        if tid is None:
+            tid = class_tids[key] = len(class_tids) + 1
+            out.append({"ph": "M", "pid": _SCHED_PID, "tid": tid,
+                        "name": "thread_name", "args": {"name": key}})
+        return tid
+
     for ev in sorted(events, key=lambda e: e.ts):
         if ev.kind == "superstep":
-            key = ev.klass or "?"
-            tid = class_tids.get(key)
-            if tid is None:
-                tid = class_tids[key] = len(class_tids) + 1
-                out.append({"ph": "M", "pid": _SCHED_PID, "tid": tid,
-                            "name": "thread_name", "args": {"name": key}})
-            out.append({"ph": "X", "pid": _SCHED_PID, "tid": tid,
+            out.append({"ph": "X", "pid": _SCHED_PID,
+                        "tid": sched_tid(ev.klass or "?"),
                         "name": "superstep", "cat": "dispatch",
                         "ts": us(ev.ts), "dur": ev.dur_s * 1e6,
                         "args": _json_safe(ev.attrs)})
+        elif ev.kind in SPAN_KINDS:
+            args = {"class": ev.klass, **ev.attrs}
+            out.append({"ph": "X", "pid": _SCHED_PID,
+                        "tid": sched_tid(f"thread {args.pop('thread', '?')}"),
+                        "name": ev.kind, "cat": "span",
+                        "ts": us(ev.ts), "dur": ev.dur_s * 1e6,
+                        "args": _json_safe(args)})
         elif ev.kind in ("publish", "spill", "refault", "evict"):
             out.append({"ph": "i", "pid": _STORE_PID, "tid": 1,
                         "name": ev.kind, "cat": "store",
                         "ts": us(ev.ts), "s": "t",
                         "args": _json_safe(ev.attrs)})
     return {"traceEvents": out, "displayTimeUnit": "ms"}
+
+
+# ---------------------------------------------------------------------------
+# device scopes of a compiled program
+# ---------------------------------------------------------------------------
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s+=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+_SCOPE = re.compile(re.escape(SCOPE_PREFIX) + r"[a-z_]+")
+
+
+def scope_of(op_name: str) -> str:
+    """The innermost ``gravfm.*`` scope of an HLO ``op_name``
+    (``jit(f)/while/body/gravfm.deliver/gravfm.stats/reduce_sum`` ->
+    ``gravfm.stats``; a transform may wrap a component, as in
+    ``vmap(gravfm.init)``); "" when it has none."""
+    found = _SCOPE.findall(op_name)
+    return found[-1] if found else ""
+
+
+def hlo_op_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:
+    """``(module name, {instruction name: device scope})`` of a compiled
+    program's HLO text (``Compiled.as_text()``). The scope comes from
+    the instruction's ``op_name`` metadata; a fusion without one takes
+    its fused computation's root's. Instruction names are unique in a
+    module, and they are the op names of the profiler's "XLA Ops" line,
+    whose "XLA Modules" line names the module."""
+    module = ""
+    op_names: Dict[str, str] = {}
+    calls: Dict[str, str] = {}
+    roots: Dict[str, str] = {}
+    comp = ""
+    for line in hlo_text.splitlines():
+        if not module:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+                continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            m = _HLO_COMPUTATION.match(line)
+            if m is not None:
+                comp = m.group(1)
+            continue
+        name = m.group(2)
+        if m.group(1):
+            roots[comp] = name
+        op = _HLO_OP_NAME.search(line)
+        op_names[name] = op.group(1) if op else ""
+        c = _HLO_CALLS.search(line)
+        if c is not None:
+            calls[name] = c.group(1)
+
+    def resolve(name: str, depth: int = 0) -> str:
+        scope = scope_of(op_names.get(name, ""))
+        if scope or depth > 8 or name not in calls:
+            return scope
+        root = roots.get(calls[name])
+        return resolve(root, depth + 1) if root else ""
+
+    return module, {name: resolve(name) for name in op_names}

@@ -76,6 +76,7 @@ into the service's stats endpoint.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -235,6 +236,12 @@ class GraphStore:
     def _emit(self, kind: str, **fields) -> None:
         if self._trace is not None:
             self._trace.emit(kind, **fields)
+
+    def _span(self, kind: str, **fields):
+        """An interval span on the attached bus (a no-op without one)."""
+        if self._trace is None:
+            return contextlib.nullcontext()
+        return self._trace.span(kind, **fields)
 
     @property
     def _spill_enabled(self) -> bool:
@@ -737,9 +744,11 @@ class GraphStore:
                 # array-for-array identical to the original
                 # (partitioners are deterministic anyway; this also
                 # skips their O(V)/O(E) host work on the fault path)
-                pg = partition_graph(graph, num_shards, method=method,
-                                     pad_multiple=pad_multiple,
-                                     part_of=part_of)
+                with self._span("partition", graph_id=graph_id,
+                                version=entry.version):
+                    pg = partition_graph(graph, num_shards, method=method,
+                                         pad_multiple=pad_multiple,
+                                         part_of=part_of)
             if fault and was_resident:
                 for fn in self._refault_listeners:
                     fn(graph_id, entry.version)

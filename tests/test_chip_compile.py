@@ -102,7 +102,7 @@ def test_engine_batch_compiles_for_v5e(one_chip, small_pg):
     eng = Engine(ALG.bfs(), small_pg, backend="pallas")
     data = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
                         eng._data)
-    compiled = eng._batch_step.lower(
+    compiled = eng._make_batch_program(8).lower(
         data, _sds((), jnp.int32, one_chip),
         {"root": _sds((8,), jnp.int32, one_chip)}).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -122,7 +122,7 @@ def test_shard_engine_batch_compiles_for_v5e_2x2(topo, small_pg, exchange):
     eng._data = jax.tree.map(
         lambda s: _sds(s.shape, s.dtype, sharded),
         abstract_shard_data(meta, exchange=exchange))
-    fn = eng._make_run_batch(100, ("root",))
+    fn = eng._make_run_batch(100, ("root",), 8)
     compiled = fn.lower(eng._data,
                         {"root": jax.ShapeDtypeStruct((8,), jnp.int32)}
                         ).compile()

@@ -1,13 +1,11 @@
-"""Metrics registry, superstep phase profiler, and SLO watchdog.
+"""Metrics registry and SLO watchdog.
 
 MetricsRegistry mechanics (counter/gauge/histogram recording, the
 per-family series cap, disabled no-op), the Prometheus text exposition
 (line grammar, counter monotonicity across scrapes, cumulative
 histogram buckets), the service's metrics endpoint fed by the stats
 snapshot (including tiny-capacity TraceBus drop counts and the
-per-tenant latency window fix), perfmodel's per-phase projection hook,
-profiled-mode phase attribution (bit-identical results, phase sums
-accounting for the superstep wall), and the watchdog's firing/resolved
+per-tenant latency window fix), and the watchdog's firing/resolved
 alert state machines under an injected stall and an injected perfmodel
 drift."""
 import re
@@ -21,7 +19,8 @@ from repro.core import perfmodel
 from repro.service import (GraphQueryService, MetricsRegistry,
                            QueryRequest, ServiceStats, Watchdog,
                            WatchdogConfig, class_key)
-from repro.service.metrics import DEFAULT_BUCKETS, Histogram
+from repro.service.metrics import (DEFAULT_BUCKETS, Histogram,
+                                   feed_service_snapshot)
 
 
 @pytest.fixture(scope="module")
@@ -254,114 +253,6 @@ def test_queue_wait_percentiles_in_snapshot(small_graph):
 
 
 # ---------------------------------------------------------------------------
-# perfmodel per-phase projection hook
-# ---------------------------------------------------------------------------
-
-def test_phase_projection_maps_terms():
-    wl = perfmodel.Workload(num_vertices=10000, num_edges=80000)
-    lim = perfmodel.limits(perfmodel.PAPER_PLATFORM,
-                           perfmodel.PAPER_ALGOS["bfs"], wl, n_nodes=4)
-    proj = perfmodel.phase_projection(lim)
-    assert set(proj) == set(perfmodel.PHASE_TERMS)
-    assert proj["scatter"] == lim["L_mem"]
-    assert proj["combine"] == proj["apply"] == lim["L_PE"]
-    assert proj["exchange"] == lim["L_if"]
-    assert proj["probe"] is None
-
-
-# ---------------------------------------------------------------------------
-# superstep phase profiler
-# ---------------------------------------------------------------------------
-
-def _profiled_pair(small_graph, **kw):
-    out = {}
-    for profile in (False, True):
-        svc = _service(small_graph, scheduling="continuous", slots=4,
-                       result_cache_size=0, profile_phases=profile, **kw)
-        res = [svc.query("g", "bfs", root=r) for r in range(4)]
-        out[profile] = (svc, res)
-    return out
-
-
-def test_profiled_results_bit_identical(small_graph):
-    pair = _profiled_pair(small_graph)
-    for a, b in zip(pair[False][1], pair[True][1]):
-        assert a.supersteps == b.supersteps
-        assert a.messages == b.messages
-        for k in a.state:
-            assert np.array_equal(np.asarray(a.state[k]),
-                                  np.asarray(b.state[k])), k
-
-
-def test_profiled_superstep_events_carry_phase_split(small_graph):
-    svc, _ = _profiled_pair(small_graph)[True]
-    ev = [e for e in svc.trace.snapshot() if e.kind == "superstep"]
-    assert ev
-    for e in ev:
-        phases = e.attrs["phase"]
-        assert set(phases) == {"scatter", "combine", "apply", "probe"}
-        assert all(v >= 0.0 for v in phases.values())
-    # and the per-class histograms saw every phase
-    snap = svc.metrics_snapshot()
-    series = snap["gravfm_superstep_phase_seconds"]["series"]
-    assert {s["labels"]["phase"] for s in series} == \
-        {"scatter", "combine", "apply", "probe"}
-    # compile-tainted supersteps are excluded from the histograms (they
-    # still carry phase attrs on the trace), so count <= events — but
-    # every phase sees the same execution supersteps
-    counts = {s["histogram"]["count"] for s in series}
-    assert len(counts) == 1
-    assert 1 <= counts.pop() <= len(ev)
-
-
-def test_unprofiled_superstep_events_have_no_phase(small_graph):
-    svc, _ = _profiled_pair(small_graph)[False]
-    ev = [e for e in svc.trace.snapshot() if e.kind == "superstep"]
-    assert ev and all("phase" not in e.attrs for e in ev)
-
-
-def test_phase_times_account_for_superstep_wall():
-    """The phase split must explain the profiled superstep wall: the
-    sum of phase times lands within 10% of the dispatch wall the trace
-    event measured around the same superstep (the residue is host glue
-    between phase dispatches). Compared against the *profiled* wall —
-    on CPU the split dispatch loses XLA fusion across phase boundaries,
-    so profiled absolute walls sit above the fused path's (the known
-    cost of profiled mode, see README); a loose 2.5x cross-check
-    bounds that distortion. A sizeable graph so compute dominates
-    dispatch overhead; 3 attempts ride out scheduler jitter."""
-    g = G.uniform(20000, 8.0, seed=1).symmetrized()
-    last = None
-    for _ in range(3):
-        svcs = {}
-        for profile in (False, True):
-            svc = GraphQueryService(num_shards=2, scheduling="continuous",
-                                    slots=4, result_cache_size=0,
-                                    profile_phases=profile)
-            svc.add_graph("g", g)
-            svc.warm("g", "bfs")
-            for r in range(4):
-                svc.query("g", "bfs", root=r)
-            svcs[profile] = svc
-        prof = [e for e in svcs[True].trace.snapshot()
-                if e.kind == "superstep"]
-        fused = [e for e in svcs[False].trace.snapshot()
-                 if e.kind == "superstep"]
-        phase_sum = sum(sum(e.attrs["phase"].values()) for e in prof)
-        prof_wall = sum(e.dur_s for e in prof)
-        fused_wall = sum(e.dur_s for e in fused)
-        ratio = phase_sum / prof_wall
-        last = (ratio, phase_sum, fused_wall)
-        if 0.9 <= ratio <= 1.1 and phase_sum < 2.5 * fused_wall:
-            return
-    ratio, phase_sum, fused_wall = last
-    raise AssertionError(
-        f"phase sum explains {ratio:.1%} of the profiled superstep wall "
-        f"(want 90-110%); phase_sum={phase_sum:.4f}s "
-        f"fused_wall={fused_wall:.4f}s")
-
-
-# ---------------------------------------------------------------------------
 # SLO watchdog
 # ---------------------------------------------------------------------------
 
@@ -470,3 +361,19 @@ def test_watchdog_thread_lifecycle(small_graph):
     finally:
         svc.stop()
     assert svc.watchdog is None
+
+
+def test_device_wait_percentiles_fed_to_prometheus():
+    stats = ServiceStats(latency_window=4)
+    for i in range(100):
+        stats.record_device_wait(float(i))
+        stats.record_queue_wait(1.0)
+    snap = stats.snapshot()
+    # the last 4 samples (96..99), apart from the queue wait
+    assert 96.0 <= snap["device_wait_p50_ms"] <= snap["device_wait_p95_ms"]
+    assert snap["queue_wait_p95_ms"] == 1.0
+    reg = MetricsRegistry()
+    feed_service_snapshot(reg, snap)
+    samples = _parse_exposition(reg.expose_text())
+    assert samples["gravfm_device_wait_p50_ms"] == snap["device_wait_p50_ms"]
+    assert samples["gravfm_device_wait_p95_ms"] == snap["device_wait_p95_ms"]

@@ -472,18 +472,21 @@ def test_store_emits_residency_transitions(small_graph):
     store.set_trace(bus)
     store.publish("a", small_graph)
     kinds = [e.kind for e in bus.snapshot()]
-    assert kinds == ["publish"]
-    ev = bus.snapshot()[0]
+    # the publish instant, then the partition span of its materialization
+    assert kinds == ["publish", "partition"]
+    ev, part = bus.snapshot()
     assert ev.attrs["graph_id"] == "a" and ev.attrs["version"] == 1
     assert ev.attrs["num_edges"] == small_graph.num_edges
-    # spill (policy evict), then refault on acquire
+    assert part.attrs["graph_id"] == "a" and part.dur_s > 0.0
+    # spill (policy evict), then refault on acquire: the host copy of
+    # the layout survives, so nothing is partitioned again
     assert store.evict("a")
     kinds = [e.kind for e in bus.snapshot()]
-    assert kinds == ["publish", "spill"]
+    assert kinds == ["publish", "partition", "spill"]
     with store.acquire("a"):
         pass
     kinds = [e.kind for e in bus.snapshot()]
-    assert kinds == ["publish", "spill", "refault"]
+    assert kinds == ["publish", "partition", "spill", "refault"]
     refault = bus.snapshot()[-1]
     assert refault.attrs["cold"] is False and refault.dur_s >= 0.0
     # forced discard -> evict event
