@@ -8,6 +8,7 @@ an ``active`` bit in state, apply reads and clears it and issues the update.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -123,9 +124,9 @@ def pagerank(num_supersteps: int = 30, damping: float = 0.85) -> GasKernel:
 
 
 # ---------------------------------------------------------------------------
-# SSSP — beyond-paper. Message key = candidate distance (min-combined);
-# the parent pointer travels as an argmin carry (engine resolves the min
-# sender id among the winning distances — deterministic, 32-bit payloads).
+# SSSP — beyond-paper. Message key = candidate distance (min-combined).
+# The loop carries distances only; the parent pointers are resolved once,
+# from the final distances, by ``finalize``.
 # ---------------------------------------------------------------------------
 
 def sssp(root: int = 0) -> GasKernel:
@@ -135,7 +136,9 @@ def sssp(root: int = 0) -> GasKernel:
         dist = jnp.where(is_root, 0.0, jnp.inf).astype(jnp.float32)
         return {
             "dist": dist,
-            "parent": jnp.where(is_root, root, -1).astype(jnp.int32),
+            # superstep whose gather last lowered dist; -1: never (the
+            # root, and every vertex not reached)
+            "t": jnp.full(vert_gid.shape, -1, jnp.int32),
             "active": is_root & valid,
         }
 
@@ -149,22 +152,76 @@ def sssp(root: int = 0) -> GasKernel:
     def scatter(payload, weight, src_gid, src_outdeg):
         return payload + weight
 
-    def scatter_carry(payload, weight, src_gid, src_outdeg):
-        return src_gid
-
-    def gather(state, combined, carry, got, superstep):
+    def gather(state, combined, got, superstep):
         better = got & (combined < state["dist"])
         return {
             "dist": jnp.where(better, combined, state["dist"]),
-            "parent": jnp.where(better, carry, state["parent"]),
+            "t": jnp.where(better, superstep, state["t"]),
             "active": state["active"] | better,
         }
+
+    def finalize(state, vert_gid, view):
+        """Parent pointers from the final ``dist`` and ``t``.
+
+        For a reached vertex v other than the root, its candidates are
+        the in-neighbours u with fl(dist[u] + w_uv) <= dist[v] (at a
+        fixed point ``<=`` is ``==``; a query stopped by the superstep
+        cap still gets a neighbour). parent[v] is the smallest candidate
+        id with dist[u] < dist[v]; where there is none (zero weights, or
+        weights too small to change a float32 sum), it is, among the
+        candidates with dist[u] == dist[v], the one with the smallest
+        t[u], then the smallest id. The root is its own parent; an
+        unreached vertex has -1.
+
+        A parent always exists, and the parent graph is a tree: (dist,
+        t) strictly decreases along every parent edge. Take the sender
+        u* whose message last lowered dist[v], in superstep t[v], with
+        the distance d it held when it sent (weights are >= 0, so d <=
+        dist[v]). If u* improved again, dist[u*] < d <= dist[v] and
+        fl(dist[u*] + w) <= fl(d + w) = dist[v]: a strictly shorter
+        candidate. Otherwise dist[u*] = d and u* was lowered (or is the
+        root, t = -1) before it sent: t[u*] < t[v], so the fallback's
+        smallest t is below t[v].
+
+        The fallback's two segment-mins run behind one ``lax.cond`` for
+        the whole batch, only when some vertex needs them.
+        """
+        dist, t = state["dist"], state["t"]
+
+        def candidates():
+            # recomputed inside the tie-break rather than held across
+            # it: on the chip each (queries, lanes) array is gigabytes
+            du, dv = view.src("dist"), view.dst(dist)
+            msg = scatter(du, view.w, view.src_gid, view.src_outdeg)
+            return du, dv, view.valid & (msg <= dv)
+
+        du, dv, on_path = candidates()
+        parent = view.min(jnp.where(on_path & (du < dv), view.src_gid,
+                                    INT_MAX))
+        reached = dist < jnp.inf
+        root = reached & (t < 0)
+        need = reached & ~root & (parent == INT_MAX)
+
+        def tie_break(parent):
+            du, dv, on_path = candidates()
+            tu = view.src("t")
+            tied = on_path & (du == dv) & view.dst(need)
+            first_t = view.min(jnp.where(tied, tu, INT_MAX))
+            first = tied & (tu == view.dst(first_t))
+            return jnp.where(need, view.min(
+                jnp.where(first, view.src_gid, INT_MAX)), parent)
+
+        parent = jax.lax.cond(view.any(need), tie_break, lambda p: p,
+                              parent)
+        parent = jnp.where(root, vert_gid, jnp.where(reached, parent, -1))
+        return ({**state, "parent": parent.astype(jnp.int32)},
+                {"parent_fallback": view.count(need)})
 
     return GasKernel(
         name="sssp", init_state=init_state, apply=apply, scatter=scatter,
         gather=gather, combiner="min", msg_dtype=jnp.float32,
-        carry_dtype=jnp.int32, scatter_carry=scatter_carry,
-        update_bits=32, message_bits=64, query_params=("root",))
+        finalize=finalize, update_bits=32, message_bits=32,
+        query_params=("root",))
 
 
 # ---------------------------------------------------------------------------

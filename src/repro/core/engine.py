@@ -42,7 +42,7 @@ from ..kernels import ref as kref
 from ..kernels.edge_gather import segment_combine_windows
 from .gas import GasKernel
 from .partition import PartitionedGraph
-from .stepper import LaneStepper, SuperstepProgram
+from .stepper import LaneStepper, LaneView, SuperstepProgram
 
 __all__ = ["Engine", "EngineResult", "collect"]
 
@@ -78,7 +78,7 @@ class _GravfmData(NamedTuple):
     w: jnp.ndarray              # (L,) f32
     lane_valid: jnp.ndarray     # (L,) bool
     lane_remote: jnp.ndarray    # (L,) bool: src shard != dst shard
-    seg: jnp.ndarray            # (L,) int32 clipped segment ids (carry path)
+    seg: jnp.ndarray            # (L,) int32 clipped segment ids (finalize)
     # Pallas tile layout (backend="pallas"; None for "ref")
     wid: Optional[jnp.ndarray] = None      # (n_tiles,) int32
     rel: Optional[jnp.ndarray] = None      # (L,) int32
@@ -278,23 +278,11 @@ class Engine:
             got_full = self._combine(data, gv, "max")
             got = got_full.reshape(P, Vm + 1)[:, :Vm] > 0
 
-        carry = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, data.w, data.src_gid,
-                                    data.src_outdeg)
-            acc_at_lane = jnp.take(acc_full, jnp.minimum(
-                data.seg, self._num_segments - 1))
-            winner = act & (masked == acc_at_lane)
-            cmasked = jnp.where(winner, cvals, cident)
-            carry_full = self._combine(data, cmasked, "min")
-            carry = carry_full.reshape(P, Vm + 1)[:, :Vm]
-
         with jax.named_scope("gravfm.stats"):
             n_msgs = jnp.sum(act.astype(jnp.int32))
             n_remote_msgs = jnp.sum(
                 (act & data.lane_remote).astype(jnp.int32))
-        return acc, got, carry, {"n_msgs": n_msgs, "n_remote": n_remote_msgs}
+        return acc, got, {"n_msgs": n_msgs, "n_remote": n_remote_msgs}
 
     def _deliver_gravf(self, data: _GravfData, payload, active):  # analysis: traced
         """Source-side scatter, unicast exchange (paper Fig. 4 left)."""
@@ -327,25 +315,47 @@ class Engine:
                 seg.reshape(-1), S, "max")
             got = got_full.reshape(P, Vm + 1)[:, :Vm] > 0
 
-        carry = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, data.pair_w, data.pair_src_gid,
-                                    data.pair_src_outdeg)
-            crecv = jnp.swapaxes(jnp.where(act, cvals, cident), 0, 1)
-            acc_at_edge = jnp.take(
-                acc_full, jnp.minimum(seg.reshape(-1), S - 1)).reshape(seg.shape)
-            winner = recv_act & (recv == acc_at_edge)
-            cmasked = jnp.where(winner, crecv, cident)
-            carry_full = kref.segment_combine(
-                cmasked.reshape(-1), seg.reshape(-1), S, "min")
-            carry = carry_full.reshape(P, Vm + 1)[:, :Vm]
-
         with jax.named_scope("gravfm.stats"):
             n_msgs = jnp.sum(act.astype(jnp.int32))
             cross = ~jnp.eye(P, dtype=bool)[:, :, None]
             n_remote = jnp.sum((act & cross).astype(jnp.int32))
-        return acc, got, carry, {"n_msgs": n_msgs, "n_remote": n_remote}
+        return acc, got, {"n_msgs": n_msgs, "n_remote": n_remote}
+
+    def _edge_view(self, data, state) -> LaneView:  # analysis: traced
+        """The EdgeView of this engine's receiver-side lanes: the CSC
+        lanes (gravfm), or the pair blocks as the receiver folds them
+        after the exchange (gravf)."""
+        P, Vm = self._P, self._Vm
+        if self.mode == "gravfm":
+            src_slot, seg = data.src_slot, data.seg
+            attrs = (data.w, data.src_gid, data.src_outdeg, data.lane_valid)
+
+            def combine(vals, combiner):
+                return self._combine(data, vals, combiner)
+        else:
+            shard = jnp.arange(P, dtype=jnp.int32)[:, None, None]
+
+            def recv(a):  # source-side (P, P, E2) -> receiver lanes
+                return jnp.swapaxes(a, 0, 1).reshape(-1)
+
+            src_slot = recv(shard * Vm + data.pair_src_local)
+            seg = (shard * (Vm + 1) + data.recv_dst_local).reshape(-1)
+            attrs = tuple(map(recv, (data.pair_w, data.pair_src_gid,
+                                     data.pair_src_outdeg,
+                                     data.pair_valid)))
+
+            def combine(vals, combiner):
+                return kref.segment_combine(vals, seg, P * (Vm + 1),
+                                            combiner)
+
+        def src_of(key):
+            return jax.vmap(lambda x: jnp.take(x.reshape(-1), src_slot))(
+                state[key])
+
+        w, src_gid, src_outdeg, valid = attrs
+        return LaneView(src_of=src_of, seg=seg, combine=combine,
+                        vshape=(P, Vm), w=w, src_gid=src_gid,
+                        src_outdeg=src_outdeg, valid=valid)
 
     # ------------------------------------------------------------------
     def _make_program(self) -> SuperstepProgram:
@@ -383,20 +393,30 @@ class Engine:
 
         return SuperstepProgram(self.kernel, deliver,
                                 init_stats=init_stats,
-                                update_stats=update_stats)
+                                update_stats=update_stats,
+                                edge_view=self._edge_view)
 
     def _make_batch_program(self, batch: int):
         """The jitted program run_batch dispatches for ``batch`` queries:
         a leading query axis on the per-query kwargs. vmap of the
         while_loop freezes finished queries' carries (their cond is
         False), so quiescent queries ride along at zero semantic cost
-        until the whole batch terminates. One jit per batch size, so
-        that each compiled program has its own module name."""
+        until the whole batch terminates; the kernel's finalize then
+        runs after the loop, outside the vmap. One jit per batch
+        size, so that each compiled program has its own module name."""
         fn = self._batch_steps.get(batch)
         if fn is None:
-            fn = self._batch_steps[batch] = jax.jit(jax.vmap(
-                named(self._loop, f"{self._name}_batch{batch}"),
-                in_axes=(None, None, 0)))
+            prog = self._prog
+
+            def run_batch(data, cap, query_kwargs):
+                self.traces += 1  # trace-time side effect (see run)
+                c = jax.vmap(lambda kw: prog.while_run(
+                    data, cap, self.params, kw))(query_kwargs)
+                c = prog.finalize(data, c)
+                return c.state, c.superstep, c.stats
+
+            fn = self._batch_steps[batch] = jax.jit(
+                named(run_batch, f"{self._name}_batch{batch}"))
         return fn
 
     def _make_loop(self):
@@ -405,6 +425,7 @@ class Engine:
         def loop(data, cap, query_kwargs):
             self.traces += 1  # Python side effect: runs at trace time only
             c = prog.while_run(data, cap, self.params, query_kwargs)
+            c = prog.finalize_one(data, c)
             return c.state, c.superstep, c.stats
 
         return loop

@@ -60,9 +60,9 @@ Concretely:
       consume instead of after it; consume/merge order is unchanged.
   unicast/combined — the ``all_to_all`` payload is chunked into column
       windows folded one behind the collective; per-window partials merge
-      with the ring schedule's lexicographic ``merge_carry`` (exact for
-      min/max combiners, the same construction the ring/unicast equality
-      test already proves). Kernels with ``got_from_identity`` skip the
+      with the combiner (exact for min/max combiners, the same
+      construction the ring/unicast equality test already proves).
+      Kernels with ``got_from_identity`` skip the
       activity (and sync-combined's per-slot got) streams entirely —
       activity is recovered as ``recv != identity`` — so the overlapped
       wire carries fewer collective launches than the synchronous one
@@ -71,6 +71,11 @@ Concretely:
 
 Both schedules are traced once per (width, overlap) at warm; toggling
 ``overlap`` per request re-traces nothing.
+
+A kernel's ``finalize`` (SSSP's parent pointers) runs once after the
+loop, whatever the exchange: an ``all_gather`` of each state leaf it
+reads at the sources, when it reads it, and a local pass over each
+shard's CSC in-edge lanes (``_edge_view``).
 """
 from __future__ import annotations
 
@@ -91,8 +96,8 @@ from ..kernels import ref as kref
 from .engine import named, span
 from .gas import GasKernel
 from .partition import PartitionedGraph
-from .stepper import (LaneStepperBase, StepCarry, SuperstepProgram,
-                      select_lanes)
+from .stepper import (LaneStepperBase, LaneView, StepCarry,
+                      SuperstepProgram, select_lanes)
 
 __all__ = ["ShardEngine", "ShardLaneStepper", "build_shard_data",
            "ShardData"]
@@ -120,6 +125,17 @@ def _count(mask):
     the ``gravfm.stats`` scope inside the edge pass."""
     with jax.named_scope("gravfm.stats"):
         return jnp.sum(mask.astype(jnp.int32))
+
+
+def _counters(stats):
+    """The kernel finalize's counters among a program's stats: every
+    entry but the loop's own ``messages`` and ``words``."""
+    return {k: v for k, v in stats.items() if k not in ("messages", "words")}
+
+
+def _psum_counters(stats):
+    """:func:`_counters` summed over the shards (inside shard_map)."""
+    return {k: jax.lax.psum(v, AXIS) for k, v in _counters(stats).items()}
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
@@ -484,6 +500,7 @@ class ShardEngine:
         return SuperstepProgram(self.kernel, deliver,
                                 init_stats=init_stats,
                                 update_stats=update_stats,
+                                edge_view=self._edge_view,
                                 global_any=global_any)
 
     # ---------------- per-shard delivery kernels ----------------------
@@ -528,18 +545,33 @@ class ShardEngine:
         else:
             gv = jnp.where(act, 1, 0).astype(jnp.int32)
             got = self._local_combine(gv, d, "max")[: m.v_max] > 0
-        carry = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, d.w, d.src_gid, d.src_outdeg)
-            acc_pad = jnp.concatenate(
-                [acc, jnp.full((1,), ident, acc.dtype)])
-            winner = act & (masked == jnp.take(
-                acc_pad, jnp.minimum(d.seg, m.v_max)))
-            cmasked = jnp.where(winner, cvals, cident)
-            carry = self._local_combine(cmasked, d, "min")[: m.v_max]
         n_msgs = _count(act)
-        return acc, got, carry, n_msgs
+        return acc, got, n_msgs
+
+    def _edge_view(self, d, state) -> LaneView:  # analysis: traced
+        """The EdgeView of a shard's CSC in-edge lanes. ``src(key)``
+        exchanges state leaf ``key`` alone, (Q, Vm) per shard, with one
+        ``all_gather``; the pass moves nothing else between shards.
+        ``any`` is taken over every shard, so a ``lax.cond`` on it takes
+        the same branch on each, and a branch may exchange a leaf too."""
+        m = self.meta
+
+        def src_of(key):
+            with jax.named_scope("gravfm.exchange"):
+                g = xc.all_gather(state[key], AXIS)          # (P, Q, Vm)
+            full = jnp.swapaxes(g, 0, 1).reshape(g.shape[1], -1)
+            return jax.vmap(lambda x: jnp.take(x, d.src_slot))(full)
+
+        def combine(vals, combiner):
+            return self._local_combine(vals, d, combiner)
+
+        def any_of(pred):
+            return jax.lax.pmax(jnp.any(pred).astype(jnp.int32), AXIS) > 0
+
+        return LaneView(src_of=src_of, seg=d.seg, combine=combine,
+                        any_of=any_of, vshape=(m.v_max,), w=d.w,
+                        src_gid=d.src_gid, src_outdeg=d.src_outdeg,
+                        valid=d.lane_valid)
 
     # ---------------- exchanges ---------------------------------------
     def _deliver_allgather(self, d, payload, active):  # analysis: traced
@@ -548,9 +580,9 @@ class ShardEngine:
         act = xc.all_gather(active, AXIS)
         # actual wire: the DENSE padded update array goes to every peer
         words = jnp.float32(m.v_max * (m.P - 1))
-        acc, got, carry, n_msgs = self._consume(
+        acc, got, n_msgs = self._consume(
             d, upd.reshape(-1), act.reshape(-1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_frontier(self, d, payload, active):  # analysis: traced
         """Compact ACTIVE updates to (id, payload) pairs; broadcast the
@@ -592,8 +624,8 @@ class ShardEngine:
         sel = jnp.minimum(sel, len(caps) - 1)
         pf, af, words = jax.lax.switch(sel, [branch(c) for c in caps],
                                        operand=None)
-        acc, got, carry, n_msgs = self._consume(d, pf, af)
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        acc, got, n_msgs = self._consume(d, pf, af)
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _combine2(self, a, b):  # analysis: traced
         """Two-operand fold of the kernel's combiner monoid."""
@@ -601,20 +633,6 @@ class ShardEngine:
         if k.combiner == "add":
             return a + b
         return jnp.minimum(a, b) if k.combiner == "min" else jnp.maximum(a, b)
-
-    def _merge_carry(self, ckey, ccar, acc_q, car_q):  # analysis: traced
-        """Lexicographic fold of (key, carry) candidates — the two-level
-        winner select the ring, and the windowed overlapped folds, use to
-        keep SSSP's carried parent bit-identical to the one-shot fold."""
-        k = self.kernel
-        if k.combiner == "min":
-            better = acc_q < ckey
-        else:
-            better = acc_q > ckey
-        equal = acc_q == ckey
-        ccar = jnp.where(better, car_q,
-                         jnp.where(equal, jnp.minimum(ccar, car_q), ccar))
-        return self._combine2(ckey, acc_q), ccar
 
     def _ring_bucket_consume(self, d, q, chunk_payload,  # analysis: traced
                              chunk_active):
@@ -632,18 +650,7 @@ class ShardEngine:
         acc_q = kref.segment_combine(masked, seg, m.v_max, k.combiner)
         gv = kref.segment_combine(
             jnp.where(act, 1, 0).astype(jnp.int32), seg, m.v_max, "max")
-        car_q = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, d.rb_w[q], d.rb_src_gid[q],
-                                    d.rb_src_outdeg[q])
-            acc_pad = jnp.concatenate(
-                [acc_q, jnp.full((1,), ident, acc_q.dtype)])
-            win = act & (masked == jnp.take(acc_pad,
-                                            jnp.minimum(seg, m.v_max)))
-            car_q = kref.segment_combine(
-                jnp.where(win, cvals, cident), seg, m.v_max, "min")
-        return acc_q, gv > 0, car_q, _count(act)
+        return acc_q, gv > 0, _count(act)
 
     def _deliver_ring(self, d, payload, active):  # analysis: traced
         """P-hop ppermute ring; each arriving chunk is consumed against the
@@ -652,40 +659,30 @@ class ShardEngine:
         k, m = self.kernel, self.meta
         me = jax.lax.axis_index(AXIS)
         ident = kops.identity_for(k.combiner, k.msg_dtype)
-        cident = (kops.identity_for("min", k.carry_dtype)
-                  if k.carry_dtype is not None else None)
         perm = [(i, (i + 1) % m.P) for i in range(m.P)]
         bucket_consume = lambda q, p, a: self._ring_bucket_consume(d, q, p, a)  # noqa: E731
-        merge_carry = self._merge_carry
         combine = self._combine2
 
         def body(i, st):
-            acc, got, n_msgs, chunk_p, chunk_a, ccar = st
+            acc, got, n_msgs, chunk_p, chunk_a = st
             q = (me - i) % m.P
-            acc_q, got_q, car_q, nm = bucket_consume(q, chunk_p, chunk_a)
-            if k.carry_dtype is not None:
-                acc, ccar = merge_carry(acc, ccar, acc_q, car_q)
-            else:
-                acc = combine(acc, acc_q)
+            acc_q, got_q, nm = bucket_consume(q, chunk_p, chunk_a)
+            acc = combine(acc, acc_q)
             got = got | got_q
             n_msgs = n_msgs + nm
             # next hop in flight while (in the compiled TPU schedule) the
             # next bucket's compute proceeds
             chunk_p = xc.ppermute(chunk_p, AXIS, perm)
             chunk_a = xc.ppermute(chunk_a, AXIS, perm)
-            return acc, got, n_msgs, chunk_p, chunk_a, ccar
+            return acc, got, n_msgs, chunk_p, chunk_a
 
         acc0 = jnp.full((m.v_max,), ident, k.msg_dtype)
         got0 = jnp.zeros((m.v_max,), bool)
-        ccar0 = (jnp.full((m.v_max,), cident, k.carry_dtype)
-                 if k.carry_dtype is not None else jnp.int32(0))
-        st = (acc0, got0, jnp.int32(0), payload, active, ccar0)
-        st = jax.lax.fori_loop(0, m.P, body, st)
-        acc, got, n_msgs, _, _, ccar = st
-        carry = ccar if k.carry_dtype is not None else None
+        st = (acc0, got0, jnp.int32(0), payload, active)
+        acc, got, n_msgs, _, _ = jax.lax.fori_loop(0, m.P, body, st)
         # ring moves the same dense bytes as allgather, in P-1 hops
         words = jnp.float32(m.v_max * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_unicast(self, d, payload, active):  # analysis: traced
         """GraVF baseline: source-side scatter + all_to_all blocks."""
@@ -708,24 +705,10 @@ class ShardEngine:
             jnp.where(recv_act, 1, 0).astype(jnp.int32).reshape(-1),
             seg.reshape(-1), m.v_max, "max")
         got = gv > 0
-        carry = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, d.pair_w, d.pair_src_gid,
-                                    d.pair_src_outdeg)
-            crecv = xc.all_to_all(jnp.where(act, cvals, cident), AXIS,
-                                       split_axis=0, concat_axis=0,
-                                       tiled=False)
-            acc_pad = jnp.concatenate([acc, jnp.full((1,), ident, acc.dtype)])
-            winner = recv_act & (recv == jnp.take(
-                acc_pad, jnp.minimum(seg, m.v_max)))
-            carry = kref.segment_combine(
-                jnp.where(winner, crecv, cident).reshape(-1),
-                seg.reshape(-1), m.v_max, "min")
         n_msgs = _count(act)
         # actual wire: all_to_all ships the PADDED per-pair blocks
         words = jnp.float32(m.e_pair_max * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_combined(self, d, payload, active):  # analysis: traced
         """Combine-at-source (the paper's degree-factor headline): fold
@@ -733,11 +716,9 @@ class ShardEngine:
         destination vertex) BEFORE the wire, then all_to_all blocks of
         ``comb_max`` slots — the receiver merges pre-combined partials
         with the same monoid, so the two-level fold is exact for min/max
-        (SSSP's lexicographic carry rides the same two-level winner
-        select as unicast) and reorder-tolerant for add."""
+        and reorder-tolerant for add."""
         k, m = self.kernel, self.meta
         R = m.comb_max
-        n_seg = m.P * (R + 1)
         vals = jnp.take(payload, d.comb_src_local)
         act = jnp.take(active, d.comb_src_local) & d.comb_valid
         msg = k.scatter(vals, d.comb_w, d.comb_src_gid, d.comb_src_outdeg)
@@ -759,36 +740,11 @@ class ShardEngine:
             jnp.where(recv_act, 1, 0).astype(jnp.int32).reshape(-1),
             seg.reshape(-1), m.v_max, "max")
         got = gv > 0
-        carry = None
-        if k.carry_dtype is not None:
-            cident = kops.identity_for("min", k.carry_dtype)
-            cvals = k.scatter_carry(vals, d.comb_w, d.comb_src_gid,
-                                    d.comb_src_outdeg)
-            # source-level winner: the edge whose key equals its
-            # (dest, rank) slot's combined key; min carry breaks ties —
-            # the per-slot (key, carry) pair then folds at the receiver
-            # exactly like a unicast edge would
-            accs_pad = jnp.concatenate(
-                [accs, jnp.full((1,), ident, accs.dtype)])
-            win = act & (masked == jnp.take(
-                accs_pad, jnp.minimum(d.comb_seg, n_seg)))
-            csend = self._comb_combine(
-                jnp.where(win, cvals, cident), d, "min"
-            ).reshape(m.P, R + 1)[:, :R]
-            crecv = xc.all_to_all(csend, AXIS, split_axis=0,
-                                       concat_axis=0, tiled=False)
-            acc_pad = jnp.concatenate(
-                [acc, jnp.full((1,), ident, acc.dtype)])
-            winner = recv_act & (recv == jnp.take(
-                acc_pad, jnp.minimum(seg, m.v_max)))
-            carry = kref.segment_combine(
-                jnp.where(winner, crecv, cident).reshape(-1),
-                seg.reshape(-1), m.v_max, "min")
         n_msgs = _count(act)
         # actual wire: one (id, payload) slot per padded remote dst —
         # the degree-factor win over unicast's e_pair_max per-edge blocks
         words = jnp.float32(2 * R * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     # ---------------- overlapped (pipelined) exchanges ------------------
     # Window count for the chunked all_to_all pipelines. Static (it fixes
@@ -827,8 +783,8 @@ class ShardEngine:
               xc.ppermute(active, AXIS, perm))
         upd, actf = jax.lax.fori_loop(0, m.P, body, st)[:2]
         words = jnp.float32(m.v_max * (m.P - 1))
-        acc, got, carry, n_msgs = self._consume(d, upd, actf)
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        acc, got, n_msgs = self._consume(d, upd, actf)
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_frontier_ov(self, d, payload, active):  # analysis: traced
         """Pipelined frontier: same capacity-bucket compaction as the
@@ -881,8 +837,8 @@ class ShardEngine:
         sel = jnp.minimum(sel, len(caps) - 1)
         pf, af, words = jax.lax.switch(sel, [branch(c) for c in caps],
                                        operand=None)
-        acc, got, carry, n_msgs = self._consume(d, pf, af)
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        acc, got, n_msgs = self._consume(d, pf, af)
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_ring_ov(self, d, payload, active):  # analysis: traced
         """Double-buffered ring: hop k+1's ppermute is issued BEFORE chunk
@@ -891,50 +847,40 @@ class ShardEngine:
         k, m = self.kernel, self.meta
         me = jax.lax.axis_index(AXIS)
         ident = kops.identity_for(k.combiner, k.msg_dtype)
-        cident = (kops.identity_for("min", k.carry_dtype)
-                  if k.carry_dtype is not None else None)
         perm = [(i, (i + 1) % m.P) for i in range(m.P)]
 
         def body(i, st):
-            acc, got, n_msgs, cur_p, cur_a, nxt_p, nxt_a, ccar = st
+            acc, got, n_msgs, cur_p, cur_a, nxt_p, nxt_a = st
             # issue hop i+2's transport before touching chunk i
             new_p = xc.ppermute(nxt_p, AXIS, perm)
             new_a = xc.ppermute(nxt_a, AXIS, perm)
             q = (me - i) % m.P
-            acc_q, got_q, car_q, nm = self._ring_bucket_consume(
+            acc_q, got_q, nm = self._ring_bucket_consume(
                 d, q, cur_p, cur_a)
-            if k.carry_dtype is not None:
-                acc, ccar = self._merge_carry(acc, ccar, acc_q, car_q)
-            else:
-                acc = self._combine2(acc, acc_q)
+            acc = self._combine2(acc, acc_q)
             got = got | got_q
             n_msgs = n_msgs + nm
-            return acc, got, n_msgs, nxt_p, nxt_a, new_p, new_a, ccar
+            return acc, got, n_msgs, nxt_p, nxt_a, new_p, new_a
 
         acc0 = jnp.full((m.v_max,), ident, k.msg_dtype)
         got0 = jnp.zeros((m.v_max,), bool)
-        ccar0 = (jnp.full((m.v_max,), cident, k.carry_dtype)
-                 if k.carry_dtype is not None else jnp.int32(0))
         st = (acc0, got0, jnp.int32(0), payload, active,
               xc.ppermute(payload, AXIS, perm),
-              xc.ppermute(active, AXIS, perm), ccar0)
-        st = jax.lax.fori_loop(0, m.P, body, st)
-        acc, got, n_msgs = st[0], st[1], st[2]
-        ccar = st[7]
-        carry = ccar if k.carry_dtype is not None else None
+              xc.ppermute(active, AXIS, perm))
+        acc, got, n_msgs = jax.lax.fori_loop(0, m.P, body, st)[:3]
         words = jnp.float32(m.v_max * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
-    def _window_pipeline(self, seg3, masked3, act3, c3,  # analysis: traced
-                         n_win, ident, cident):
+    def _window_pipeline(self, seg3, masked3, act3,  # analysis: traced
+                         n_win, ident):
         """Chunked all_to_all pipeline shared by the overlapped unicast
         and combined exchanges: the collective for column window k+1 is
         issued while window k's receive block (the double buffer riding
         the fori_loop carry) is folded into the accumulator. Per-window
-        partials merge lexicographically (``_merge_carry``), which is
-        exact for min/max combiners. ``act3 is None`` elides the activity
-        stream for got_from_identity kernels (activity is recovered as
-        ``recv != identity``); ``c3 is None`` elides the carry stream."""
+        partials merge with the combiner, which is exact for min/max
+        combiners. ``act3 is None`` elides the activity stream for
+        got_from_identity kernels (activity is recovered as ``recv !=
+        identity``)."""
         k, m = self.kernel, self.meta
         dummy = jnp.int32(0)
 
@@ -949,54 +895,34 @@ class ShardEngine:
             ba = (a2a(jax.lax.dynamic_index_in_dim(
                 act3, wi, 1, keepdims=False))
                 if act3 is not None else dummy)
-            bc = (a2a(jax.lax.dynamic_index_in_dim(
-                c3, wi, 1, keepdims=False))
-                if c3 is not None else dummy)
-            return bp, ba, bc
+            return bp, ba
 
-        def fold(wi, acc, got, ccar, bp, ba, bc):
+        def fold(wi, acc, got, bp, ba):
             seg_w = jax.lax.dynamic_index_in_dim(
                 seg3, wi, 1, keepdims=False).reshape(-1)
             recv = bp.reshape(-1)
             acc_w = kref.segment_combine(recv, seg_w, m.v_max, k.combiner)
             if act3 is not None:
-                ract = ba.reshape(-1)
                 gv = kref.segment_combine(
-                    jnp.where(ract, 1, 0).astype(jnp.int32), seg_w,
-                    m.v_max, "max")
+                    jnp.where(ba.reshape(-1), 1, 0).astype(jnp.int32),
+                    seg_w, m.v_max, "max")
                 got = got | (gv > 0)
-            else:
-                ract = recv != ident
-            if c3 is not None:
-                acc_w_pad = jnp.concatenate(
-                    [acc_w, jnp.full((1,), ident, acc_w.dtype)])
-                win_w = ract & (recv == jnp.take(
-                    acc_w_pad, jnp.minimum(seg_w, m.v_max)))
-                car_w = kref.segment_combine(
-                    jnp.where(win_w, bc.reshape(-1), cident), seg_w,
-                    m.v_max, "min")
-                acc, ccar = self._merge_carry(acc, ccar, acc_w, car_w)
-            else:
-                acc = self._combine2(acc, acc_w)
-            return acc, got, ccar
+            return self._combine2(acc, acc_w), got
 
         def body(w, st):
-            acc, got, ccar, bp, ba, bc = st
+            acc, got, bp, ba = st
             nb = issue(w + 1)     # window w+1's collective in flight...
-            acc, got, ccar = fold(w, acc, got, ccar, bp, ba, bc)  # ...now
-            return (acc, got, ccar) + nb
+            acc, got = fold(w, acc, got, bp, ba)  # ...while w folds
+            return (acc, got) + nb
 
         acc0 = jnp.full((m.v_max,), ident, k.msg_dtype)
         got0 = jnp.zeros((m.v_max,), bool)
-        ccar0 = (jnp.full((m.v_max,), cident, k.carry_dtype)
-                 if c3 is not None else dummy)
         st = jax.lax.fori_loop(
-            0, n_win - 1, body, (acc0, got0, ccar0) + issue(jnp.int32(0)))
-        acc, got, ccar = fold(jnp.int32(n_win - 1), *st)
+            0, n_win - 1, body, (acc0, got0) + issue(jnp.int32(0)))
+        acc, got = fold(jnp.int32(n_win - 1), *st)
         if act3 is None:
             got = acc != ident
-        carry = ccar if c3 is not None else None
-        return acc, got, carry
+        return acc, got
 
     def _window3(self, a, n_win, cw, fill):  # analysis: traced
         """(P, E) -> (P, n_win, cw) column windows, identity-padded."""
@@ -1018,28 +944,18 @@ class ShardEngine:
         msg = k.scatter(vals, d.pair_w, d.pair_src_gid, d.pair_src_outdeg)
         ident = kops.identity_for(k.combiner, k.msg_dtype)
         masked = jnp.where(act, msg, ident)
-        has_carry = k.carry_dtype is not None
-        cident = (kops.identity_for("min", k.carry_dtype)
-                  if has_carry else None)
         n_win = self._n_windows(m.e_pair_max)
         cw = -(-m.e_pair_max // n_win)
         masked3 = self._window3(masked, n_win, cw, ident)
         seg3 = self._window3(d.recv_dst_local, n_win, cw, m.v_max)
         act3 = (None if k.got_from_identity
                 else self._window3(act, n_win, cw, False))
-        c3 = None
-        if has_carry:
-            cvals = k.scatter_carry(vals, d.pair_w, d.pair_src_gid,
-                                    d.pair_src_outdeg)
-            c3 = self._window3(jnp.where(act, cvals, cident), n_win, cw,
-                               cident)
-        acc, got, carry = self._window_pipeline(
-            seg3, masked3, act3, c3, n_win, ident, cident)
+        acc, got = self._window_pipeline(seg3, masked3, act3, n_win, ident)
         n_msgs = _count(act)
         # reported wire: the bytes the serial schedule moves (see module
         # docstring) — keeps stats comparable across schedules
         words = jnp.float32(m.e_pair_max * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     def _deliver_combined_ov(self, d, payload, active):  # analysis: traced
         """Overlapped combine-at-source: the per-(peer, rank) partial
@@ -1047,7 +963,6 @@ class ShardEngine:
         the source-side segment-combine is the synchronous one."""
         k, m = self.kernel, self.meta
         R = m.comb_max
-        n_seg = m.P * (R + 1)
         vals = jnp.take(payload, d.comb_src_local)
         act = jnp.take(active, d.comb_src_local) & d.comb_valid
         msg = k.scatter(vals, d.comb_w, d.comb_src_gid, d.comb_src_outdeg)
@@ -1055,9 +970,6 @@ class ShardEngine:
         masked = jnp.where(act, msg, ident)
         accs = self._comb_combine(masked, d, k.combiner)       # (n_seg,)
         send = accs.reshape(m.P, R + 1)[:, :R]                 # (P, R)
-        has_carry = k.carry_dtype is not None
-        cident = (kops.identity_for("min", k.carry_dtype)
-                  if has_carry else None)
         n_win = self._n_windows(R)
         cw = -(-R // n_win) if R else 0
         masked3 = self._window3(send, n_win, cw, ident)
@@ -1068,23 +980,10 @@ class ShardEngine:
                 jnp.where(act, 1, 0).astype(jnp.int32), d, "max"
             ).reshape(m.P, R + 1)[:, :R] > 0
             act3 = self._window3(send_act, n_win, cw, False)
-        c3 = None
-        if has_carry:
-            cvals = k.scatter_carry(vals, d.comb_w, d.comb_src_gid,
-                                    d.comb_src_outdeg)
-            accs_pad = jnp.concatenate(
-                [accs, jnp.full((1,), ident, accs.dtype)])
-            win = act & (masked == jnp.take(
-                accs_pad, jnp.minimum(d.comb_seg, n_seg)))
-            csend = self._comb_combine(
-                jnp.where(win, cvals, cident), d, "min"
-            ).reshape(m.P, R + 1)[:, :R]
-            c3 = self._window3(csend, n_win, cw, cident)
-        acc, got, carry = self._window_pipeline(
-            seg3, masked3, act3, c3, n_win, ident, cident)
+        acc, got = self._window_pipeline(seg3, masked3, act3, n_win, ident)
         n_msgs = _count(act)
         words = jnp.float32(2 * R * (m.P - 1))
-        return acc, got, carry, {"n_msgs": n_msgs, "words": words}
+        return acc, got, {"n_msgs": n_msgs, "words": words}
 
     # ---------------- superstep + loop ---------------------------------
     def _shard_step(self, d: ShardData, payload, active, state, superstep):
@@ -1106,12 +1005,13 @@ class ShardEngine:
             self.traces += 1  # trace-time side effect (see Engine.traces)
             # shard_map blocks keep a size-1 leading (sharded) axis
             d = jax.tree.map(lambda a: a[0], d)
-            c = prog.while_run(d, cap, self.params, qkw)
+            c = prog.finalize_one(d, prog.while_run(d, cap, self.params, qkw))
             total_msgs = jax.lax.psum(c.stats["messages"], AXIS)
             total_words = jax.lax.psum(c.stats["words"], AXIS)
             # re-add shard axis
             state = jax.tree.map(lambda a: a[None], c.state)
-            return state, c.superstep, total_msgs, total_words
+            return (state, c.superstep, total_msgs, total_words,
+                    _psum_counters(c.stats))
 
         in_specs = jax.tree.map(lambda _: P(AXIS), self._data,
                                 is_leaf=lambda x: x is None)
@@ -1121,7 +1021,7 @@ class ShardEngine:
             named(shard_fn, self._program_name("run", overlap)),
             mesh=self.mesh,
             in_specs=(in_specs, qspec),
-            out_specs=(state_spec, P(), P(), P()))
+            out_specs=(state_spec, P(), P(), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
@@ -1168,12 +1068,14 @@ class ShardEngine:
             with jax.named_scope("gravfm.loop"):
                 _, carry = jax.lax.while_loop(
                     cond, body, (jnp.int32(0), carry))
+            carry = prog.finalize(d, carry)
             total_msgs = jax.lax.psum(carry.stats["messages"], AXIS)  # (B,)
             total_words = jax.lax.psum(
                 jnp.sum(carry.stats["words"]), AXIS)
             # re-add shard axis leading so out spec P(AXIS) shards it
             state = jax.tree.map(lambda a: a[None], carry.state)  # (1, B, ·)
-            return state, carry.superstep, total_msgs, total_words
+            return (state, carry.superstep, total_msgs, total_words,
+                    _psum_counters(carry.stats))
 
         in_specs = jax.tree.map(lambda _: P(AXIS), self._data,
                                 is_leaf=lambda x: x is None)
@@ -1182,7 +1084,7 @@ class ShardEngine:
             named(shard_fn, self._program_name(f"batch{batch}", overlap)),
             mesh=self.mesh,
             in_specs=(in_specs, qspec),
-            out_specs=(P(AXIS), P(), P(), P()))
+            out_specs=(P(AXIS), P(), P(), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
@@ -1204,10 +1106,11 @@ class ShardEngine:
               self._make_run_batch(cap, qkeys, batch, overlap))
         return fn.lower(self._data, qkw)
 
-    def _result_comm(self, words: float) -> Dict[str, Any]:
+    def _result_comm(self, words: float,
+                     counters: Dict[str, float]) -> Dict[str, Any]:
         return {"exchange_words": words, "wire_words": words,
                 "exchange": self.exchange,
-                "scheme": f"shard_{self.exchange}"}
+                "scheme": f"shard_{self.exchange}", **counters}
 
     def run(self, max_supersteps: Optional[int] = None,
             overlap: bool = False, **query_kwargs):
@@ -1229,14 +1132,16 @@ class ShardEngine:
             out = fn(self._data, qkw)
             jax.block_until_ready(out)
         with span(self.trace, "fetch"):
-            state_np, s, msgs, words = jax.tree.map(np.asarray, out)
+            state_np, s, msgs, words, ctr = jax.tree.map(np.asarray, out)
         from .engine import EngineResult, collect
         with span(self.trace, "collect"):
             return EngineResult(
                 state=collect(self.pg, state_np) if self.pg else state_np,
                 supersteps=int(s[0] if np.ndim(s) else s),
                 messages=int(msgs.reshape(-1)[0]),
-                comm=self._result_comm(float(words.reshape(-1)[0])),
+                comm=self._result_comm(
+                    float(words.reshape(-1)[0]),
+                    {k: float(v.reshape(-1)[0]) for k, v in ctr.items()}),
                 raw_state=state_np,
             )
 
@@ -1263,12 +1168,13 @@ class ShardEngine:
             jax.block_until_ready(res)
         with span(self.trace, "fetch"):
             # state leaves (P, B, ...)
-            state_np, sq, msgs, words = jax.tree.map(np.asarray, res)
+            state_np, sq, msgs, words, ctr = jax.tree.map(np.asarray, res)
         from .engine import EngineResult, collect
         with span(self.trace, "collect"):
             sq = sq.reshape(-1, sq.shape[-1])[0]
             msgs = msgs.reshape(-1, msgs.shape[-1])[0]
             words = float(words.reshape(-1)[0])
+            ctr = {k: v.reshape(-1, v.shape[-1])[0] for k, v in ctr.items()}
             out = []
             for q in range(sq.shape[0]):
                 state_q = jax.tree.map(lambda a: a[:, q], state_np)
@@ -1276,7 +1182,8 @@ class ShardEngine:
                     state=collect(self.pg, state_q) if self.pg else state_q,
                     supersteps=int(sq[q]),
                     messages=int(msgs[q]),
-                    comm=self._result_comm(words),
+                    comm=self._result_comm(
+                        words, {k: float(v[q]) for k, v in ctr.items()}),
                     raw_state=state_q,
                 ))
             return out
@@ -1346,12 +1253,13 @@ class ShardEngine:
         from .engine import EngineResult, collect
         state_q = jax.tree.map(lambda a: np.asarray(a[:, lane]),
                                carry_host.state)
+        stats = {k: v[:, lane].sum() for k, v in carry_host.stats.items()}
         return EngineResult(
             state=collect(self.pg, state_q) if self.pg else state_q,
             supersteps=int(carry_host.superstep[0, lane]),
-            messages=int(carry_host.stats["messages"][:, lane].sum()),
-            comm=self._result_comm(
-                float(carry_host.stats["words"][:, lane].sum())),
+            messages=int(stats["messages"]),
+            comm=self._result_comm(float(stats["words"]), {
+                k: float(v) for k, v in _counters(stats).items()}),
             raw_state=state_q,
         )
 
@@ -1378,6 +1286,8 @@ class ShardLaneStepper(LaneStepperBase):
     depends on the kernel's state dict and the query kwarg dtypes), then
     reused forever: steady-state admit/step/retire re-traces nothing.
     """
+
+    _lane_axis = 1  # carry leaves are (P, W, ...)
 
     def __init__(self, eng: ShardEngine, width: int,
                  overlap: bool = False):
@@ -1488,6 +1398,20 @@ class ShardLaneStepper(LaneStepperBase):
         self._fns = (with_probe(init_sm), with_probe(admit_sm),
                      with_probe(step_sm))
         self._restore = with_probe(restore_sm)
+
+        if prog.kernel.finalize is not None:
+            def finalize_fn(d, carry, lanes):
+                eng.traces += 1
+                return readd(prog.finalize(strip(d), jax.tree.map(
+                    lambda a: jnp.take(a, lanes, axis=0), strip(carry))))
+
+            # the finalized carry adds leaves (the kernel's outputs and
+            # counters): every leaf keeps the leading shard axis
+            fin = jax.jit(_shard_map(
+                finalize_fn, mesh=eng.mesh,
+                in_specs=(data_spec, carry_spec, lane_spec),
+                out_specs=P(AXIS)))
+            self._finalize = lambda c, lanes: fin(eng._data, c, lanes)
 
     def init(self, qkw):
         q = self._qdev(qkw)

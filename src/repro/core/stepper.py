@@ -28,8 +28,17 @@ The same traced ``step`` is then driven three ways:
     into freed lanes between supersteps.
 
 Both engines parameterize the program with their own ``deliver`` (which
-collective moves the updates) and stats fold; the loop structure lives
-here once.
+collective moves the updates), stats fold and ``edge_view`` (the
+:class:`~.gas.EdgeView` a kernel's ``finalize`` reads); the loop
+structure lives here once.
+
+  finalize(data, carry) -> carry
+      the kernel's pass over the in-edges once its queries have stopped
+      (SSSP's parent pointers), over a carry that leads with the query
+      axis; the identity for a kernel without one. ``while_run``'s
+      callers apply it inside the same compiled program, and a lane
+      stepper's ``fetch`` applies it to the retiring lanes alone before
+      the host copy.
 
 Because the carry is explicit, a lane is *preemptible*: between
 supersteps its carry slice is host-fetchable (``fetch_lane``) and can be
@@ -43,14 +52,14 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
-                    Tuple)
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["StepCarry", "SuperstepProgram", "LaneStepper",
-           "LaneStepperBase", "select_lanes",
+           "LaneStepperBase", "LaneView", "select_lanes",
            "LaneMeta", "LaneCheckpoint", "LaneTable", "lane_dtype",
            "PRIORITY_BOOST_S"]
 
@@ -82,32 +91,74 @@ def select_lanes(mask, new, old):
         return jax.tree.map(sel, new, old)
 
 
-class SuperstepProgram:
-    """init/step/alive for one (kernel, graph layout, deliver) triple.
+class LaneView:
+    """The :class:`~.gas.EdgeView` both engines build: edge lanes that
+    index flat per-vertex arrays, for every query of a state whose
+    leaves lead with the query axis.
 
-    ``deliver(data, payload, active)`` returns ``(acc, got, carry_vals,
-    aux)`` where ``aux`` is a dict of per-superstep scalars folded into
-    the running stats by ``update_stats(stats, data, active, aux)``
-    (``active`` is the pre-apply mask of the superstep being folded).
-    ``global_any`` reduces the local activity bit across shards
-    (identity for the global-array engine, ``pmax`` inside shard_map).
+    ``src_of(key)`` gives state leaf ``key`` at each lane's source,
+    (Q, lanes); ``any_of(pred)`` reduces a predicate to one scalar over
+    every shard. ``seg`` is each lane's destination in a query's
+    per-vertex array padded by one discard slot per shard (``vshape``
+    with its last axis one longer, flattened), as the engine's
+    segmented ``combine(vals, combiner)`` numbers its segments.
+    """
+
+    def __init__(self, *, src_of, seg, combine, vshape,
+                 w, src_gid, src_outdeg, valid, any_of=jnp.any):
+        self.src = src_of
+        self.any = any_of
+        self.w, self.src_gid, self.src_outdeg = w, src_gid, src_outdeg
+        self.valid = valid
+        self._seg, self._combine = seg, combine
+        self._padded = tuple(vshape[:-1]) + (vshape[-1] + 1,)
+
+    def dst(self, x):
+        def one(x):
+            pad = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, 1)]).reshape(-1)
+            return jnp.take(pad, jnp.minimum(self._seg, pad.size - 1))
+        return jax.vmap(one)(x)
+
+    def min(self, vals):
+        return jax.vmap(lambda v: self._combine(v, "min").reshape(
+            self._padded)[..., :-1])(vals)
+
+    def count(self, mask):
+        return jnp.sum(mask.reshape(mask.shape[0], -1), axis=1,
+                       dtype=jnp.int32)
+
+
+class SuperstepProgram:
+    """init/step/alive/finalize for one (kernel, graph layout, deliver)
+    triple.
+
+    ``deliver(data, payload, active)`` returns ``(acc, got, aux)`` where
+    ``aux`` is a dict of per-superstep scalars folded into the running
+    stats by ``update_stats(stats, data, active, aux)`` (``active`` is
+    the pre-apply mask of the superstep being folded). ``global_any``
+    reduces the local activity bit across shards (identity for the
+    global-array engine, ``pmax`` inside shard_map). ``edge_view(data,
+    state)`` builds the engine's EdgeView over a state that leads with
+    the query axis.
     """
 
     def __init__(self, kernel, deliver: Callable[..., Any], *,
                  init_stats: Callable[[], Dict[str, jnp.ndarray]],
                  update_stats: Callable[..., Dict[str, jnp.ndarray]],
+                 edge_view: Callable[..., Any],
                  global_any: Optional[Callable[[jnp.ndarray],
                                                jnp.ndarray]] = None):
         self.kernel = kernel
         self.deliver = deliver
         self.init_stats = init_stats
         self.update_stats = update_stats
+        self.edge_view = edge_view
         self.global_any = global_any or (lambda b: b)
 
     # ------------------------------------------------------------------
     # Every phase runs under a ``jax.named_scope`` (``gravfm.<phase>``:
-    # init, deliver, gather, stats, apply, cond, and loop for the loop's
-    # own lane selects; the shard engine adds exchange inside deliver):
+    # init, deliver, gather, stats, apply, cond, loop for the loop's own
+    # lane selects, and finalize; the shard engine adds exchange):
     # metadata only, so the compiled instructions are unchanged, but each
     # fusion's ``op_name`` then names the phase it came from, and the
     # service maps device ops in a profiler trace back to the phase.
@@ -128,7 +179,7 @@ class SuperstepProgram:
     def step_deliver(self, data, carry: StepCarry):
         """Scatter/exchange, the edge pass: move this superstep's pending
         updates to their receivers. Returns the opaque delivered tuple
-        ``(acc, got, carry_vals, aux)`` that ``step_combine`` folds."""
+        ``(acc, got, aux)`` that ``step_combine`` folds."""
         with jax.named_scope("gravfm.deliver"):
             return self.deliver(data, carry.payload, carry.active)
 
@@ -138,12 +189,9 @@ class SuperstepProgram:
         (the counter advances in ``step_apply``)."""
         k = self.kernel
         state, payload, active, s, stats = carry
-        acc, got, carry_v, aux = delivered
+        acc, got, aux = delivered
         with jax.named_scope("gravfm.gather"):
-            if k.carry_dtype is not None:
-                state = k.gather(state, acc, carry_v, got, s)
-            else:
-                state = k.gather(state, acc, got, s)
+            state = k.gather(state, acc, got, s)
         with jax.named_scope("gravfm.stats"):
             stats = self.update_stats(stats, data, active, aux)
         return StepCarry(state, payload, active, s, stats)
@@ -189,6 +237,25 @@ class SuperstepProgram:
         with jax.named_scope("gravfm.loop"):
             return jax.lax.while_loop(cond, body, carry)
 
+    def finalize(self, data, carry: StepCarry) -> StepCarry:
+        """The kernel's ``finalize`` over ``carry``, whose leaves lead
+        with the query axis; its counters join the stats."""
+        if self.kernel.finalize is None:
+            return carry
+        with jax.named_scope("gravfm.finalize"):
+            view = self.edge_view(data, carry.state)
+            state, counters = self.kernel.finalize(
+                carry.state, data.vert_gid, view)
+        return carry._replace(state=state,
+                              stats={**carry.stats, **counters})
+
+    def finalize_one(self, data, carry: StepCarry) -> StepCarry:
+        """:meth:`finalize` of one query's carry (no query axis)."""
+        if self.kernel.finalize is None:
+            return carry
+        one = jax.tree.map(lambda a: a[None], carry)
+        return jax.tree.map(lambda a: a[0], self.finalize(data, one))
+
 
 class LaneStepperBase:
     """Host-side plumbing shared by every lane stepper (the global-array
@@ -219,8 +286,46 @@ class LaneStepperBase:
         out = self._probe(carry)
         return np.asarray(out[0]), np.asarray(out[1])
 
-    def fetch(self, carry: StepCarry) -> StepCarry:
-        return jax.tree.map(np.asarray, carry)
+    # jitted ``(carry, lanes) -> carry``: the carry of the ``lanes``
+    # slots, gathered into a narrower slot array and passed through the
+    # kernel's finalize; None for a kernel without one. Set by subclasses,
+    # which also say which axis of a carry leaf indexes the lanes.
+    _finalize = None
+    _lane_axis = 0
+
+    def fetch_widths(self) -> List[int]:
+        """Slot-array widths ``fetch`` runs a compiled finalize at: one
+        lane, and the stepper's width; none for a kernel without a
+        finalize. Nothing between: the chip works a batch of queries in
+        rows 128 wide, so on a v5e at Graph500 scale 18 two lanes took
+        0.40 s, all 32 0.37 s and one 0.21 s."""
+        if self._finalize is None:
+            return []
+        return sorted({1, self.width})
+
+    def fetch(self, carry: StepCarry,
+              lanes: Optional[Sequence[int]] = None) -> StepCarry:
+        """Host copy of the carry of ``lanes`` (every lane when None), in
+        that order along the lane axis. A kernel's ``finalize`` runs on
+        the device first, over those lanes only: they are gathered into a
+        slot array of the narrowest ``fetch_widths`` width that holds
+        them (padded with copies of the last), so lanes still in flight
+        take no part in the pass or its tie-break."""
+        if self._finalize is None:
+            host = jax.tree.map(np.asarray, carry)
+            if lanes is None:
+                return host
+            return jax.tree.map(
+                lambda a: np.take(a, list(lanes), axis=self._lane_axis),
+                host)
+        lanes = (list(range(self.width)) if lanes is None
+                 else [int(i) for i in lanes])
+        n = len(lanes)
+        k = next(w for w in self.fetch_widths() if w >= max(n, 1))
+        idx = np.asarray(lanes + (lanes or [0])[-1:] * (k - n), np.int32)
+        out = self._finalize(carry, jnp.asarray(idx))
+        head = (slice(None),) * self._lane_axis + (slice(0, n),)
+        return jax.tree.map(lambda a: np.asarray(a)[head], out)
 
     def fetch_lane(self, carry: StepCarry, lane: int) -> StepCarry:
         """Host copy of exactly ONE lane's carry slice (the checkpoint
@@ -273,7 +378,7 @@ class LaneStepper(LaneStepperBase):
       step(carry, alive)       -> one superstep for ``alive`` lanes,
                                   everything else frozen
       probe(carry)             -> host (lane_active (W,), supersteps (W,))
-      fetch(carry)             -> host copy of the whole carry
+      fetch(carry, lanes)      -> host copy of those lanes' carry, finalized
     """
 
     def __init__(self, prog: SuperstepProgram, data, params: Dict[str, Any],
@@ -326,7 +431,15 @@ class LaneStepper(LaneStepperBase):
             c = select_lanes(fresh, new, carry)
             return (c, *probe_of(c))
 
+        def finalize_fn(d, carry, lanes):
+            hook()
+            return prog.finalize(d, jax.tree.map(
+                lambda a: jnp.take(a, lanes, axis=0), carry))
+
         self._data = data
+        if prog.kernel.finalize is not None:
+            fin = jax.jit(finalize_fn)
+            self._finalize = lambda c, lanes: fin(self._data, c, lanes)
         self._init = jax.jit(init_fn)
         self._admit = jax.jit(admit_fn)
         self._step = jax.jit(step_fn)
@@ -573,8 +686,10 @@ class LaneTable:
                         lanes=lanes, n_alive=len(lanes),
                         words=max(0.0, w1 - w0), **extra)
 
-    def fetch(self) -> StepCarry:
-        return self.stepper.fetch(self.carry)
+    def fetch(self, slots: Optional[Sequence[int]] = None) -> StepCarry:
+        """Host copy of the carry of ``slots`` (every slot when None), in
+        that order, finalized (see ``LaneStepperBase.fetch``)."""
+        return self.stepper.fetch(self.carry, slots)
 
     def release(self, slot: int) -> LaneMeta:
         """Free one retired lane's slot; returns its metadata."""

@@ -10,7 +10,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["segment_combine", "segment_combine_carry"]
+__all__ = ["segment_combine"]
 
 
 def segment_combine(vals: jnp.ndarray, seg_ids: jnp.ndarray,
@@ -28,21 +28,3 @@ def segment_combine(vals: jnp.ndarray, seg_ids: jnp.ndarray,
     else:
         raise ValueError(f"unknown combiner: {combiner}")
     return out[:num_segments]
-
-
-def segment_combine_carry(key_vals: jnp.ndarray, carry_vals: jnp.ndarray,
-                          seg_ids: jnp.ndarray, num_segments: int,
-                          combiner: str, carry_identity) -> tuple:
-    """min/max-combine on ``key_vals`` with an argmin-style carried value:
-    among lanes achieving the winning key, the min carry wins (deterministic
-    tie-break; mirrors the paper's arbitrary-order message delivery, where
-    any winning message's payload is acceptable)."""
-    assert combiner in ("min", "max")
-    acc = segment_combine(key_vals, seg_ids, num_segments, combiner)
-    clipped = jnp.minimum(seg_ids, num_segments)
-    at_edge = jnp.take(jnp.concatenate([acc, acc[-1:]]) if num_segments else acc,
-                       jnp.minimum(clipped, max(num_segments - 1, 0)))
-    winner = (key_vals == at_edge) & (seg_ids < num_segments)
-    masked_carry = jnp.where(winner, carry_vals, carry_identity)
-    carry = segment_combine(masked_carry, seg_ids, num_segments, "min")
-    return acc, carry

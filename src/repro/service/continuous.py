@@ -716,13 +716,16 @@ class ContinuousScheduler:
         done = cr.table.done_slots(cr.cap)
         if not done:
             return 0
-        host = cr.table.fetch()
+        # one device call gathers the retiring lanes, runs the kernel's
+        # finalize (SSSP's parent pointers) on them alone, and copies them
+        # to the host, in the order of ``done``
+        host = cr.table.fetch(done)
         now = time.perf_counter()
-        for i in done:
+        for j, i in enumerate(done):
             meta = cr.table.release(i)
             req, fut = meta.payload
             try:
-                res = cr.splan.engine.lane_result(host, i)
+                res = cr.splan.engine.lane_result(host, j)
             except Exception as exc:    # noqa: BLE001 — fail one lane
                 fut.set_exception(exc)
                 self._emit("retire", qid=meta.seq, tenant=req.tenant,

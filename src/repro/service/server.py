@@ -323,6 +323,10 @@ class GraphQueryService:
                 carry, qkw, np.zeros(self._slots, bool))
             carry, _, _ = splan.stepper.step(
                 carry, np.zeros(self._slots, bool))
+            # and the retirement fetch at each width it finalizes at
+            # (a kernel without a finalize compiles nothing there)
+            for k in splan.stepper.fetch_widths():
+                splan.stepper.fetch(carry, range(k))
             # pre-trace the preemption verbs too: parking and restoring
             # lanes is then also a zero-re-trace steady-state operation
             ckpt = splan.stepper.fetch_lane(carry, 0)

@@ -63,8 +63,13 @@ class FakeStepper:
         carry["steps"][alive] += 1
         return (carry, *self._probe(carry))
 
-    def fetch(self, carry):
-        return carry
+    def fetch_widths(self):
+        return [self.width]
+
+    def fetch(self, carry, lanes=None):
+        if lanes is None:
+            return carry
+        return {k: v[list(lanes)] for k, v in carry.items()}
 
     def fetch_lane(self, carry, lane):
         return {k: v[lane].copy() for k, v in carry.items()}

@@ -130,3 +130,51 @@ def test_shard_engine_batch_compiles_for_v5e_2x2(topo, small_pg, exchange):
     want = {"allgather": "all-gather", "combined": "all-to-all"}[exchange]
     assert want in text
     assert "tpu_custom_call" in text
+
+
+def test_sssp_batch_with_finalize_compiles_for_v5e(one_chip, small_pg):
+    """SSSP's served batch-32 program for one chip, with the parent pass
+    after the loop: the pass's tie-break stays one conditional for the
+    batch. Made per query under the query vmap, it becomes a select that
+    runs both branches' segment-mins every time, and here asks 27.9 MB
+    of temporaries against 2.2 MB of inputs and outputs."""
+    eng = Engine(ALG.sssp(), small_pg, backend="ref")
+    data = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                        eng._data)
+    compiled = eng._make_batch_program(32).lower(
+        data, _sds((), jnp.int32, one_chip),
+        {"root": _sds((32,), jnp.int32, one_chip)}).compile()
+    assert " conditional(" in compiled.as_text()
+    m = compiled.memory_analysis()
+    io = m.argument_size_in_bytes + m.output_size_in_bytes
+    assert 0 < m.temp_size_in_bytes <= 2 * io, (m.temp_size_in_bytes, io)
+
+
+@pytest.mark.parametrize("exchange", ["allgather", "combined"])
+def test_shard_sssp_finalize_compiles_for_v5e_2x2(topo, small_pg, exchange):
+    """SSSP's batched ShardEngine program on a 4-device mesh: after the
+    loop, the parent pass exchanges only the leaves it reads at the
+    sources, one all-gather each time it reads one: dist, and inside the
+    tie-break's conditional dist again and t; the state's activity bits
+    never move. It then reads each shard's CSC lanes."""
+    mesh = Mesh(np.array(topo.devices), ("graph",))
+    _, meta = build_shard_data(small_pg)
+    eng = ShardEngine(ALG.sssp(), meta, mesh=mesh, exchange=exchange,
+                      backend="pallas")
+    sharded = NamedSharding(mesh, PartitionSpec("graph"))
+    data = abstract_shard_data(meta, exchange=exchange)
+    # the pass reads the CSC lanes, which this exchange's loop does not
+    csc = abstract_shard_data(meta, exchange="allgather")
+    data = data._replace(**{f: c for f, c, x in zip(data._fields, csc, data)
+                            if x is None and c is not None})
+    eng._data = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sharded),
+                             data)
+    fn = eng._make_run_batch(100, ("root",), 8)
+    text = fn.lower(eng._data,
+                    {"root": jax.ShapeDtypeStruct((8,), jnp.int32)}
+                    ).compile().as_text()
+    gathers = [line for line in text.splitlines()
+               if " all-gather(" in line and "gravfm.finalize" in line]
+    assert sorted(line.split("= ")[1][:3] for line in gathers) == [
+        "f32", "f32", "s32"], gathers
+    assert " conditional(" in text

@@ -123,7 +123,8 @@ def test_join_midflight_matches_solo_pallas(deep_graph):
 
 
 def test_join_midflight_sssp_carry(graph):
-    """The argmin carry path (SSSP parent pointers) through the stepper."""
+    """SSSP through the stepper: distances, and the parent pointers the
+    retirement fetch finalizes, match a solo run."""
     pg = PT.partition_graph(graph, 4, method="greedy", pad_multiple=16)
     eng = Engine(ALG.sssp(), pg, mode="gravfm", backend="ref")
     arrivals = [(0, {"root": 0}), (2, {"root": 250}), (4, {"root": 77})]
@@ -315,12 +316,27 @@ def test_drain_keeps_admission_window_open():
     drained by the SAME drain call."""
     gate = threading.Semaphore(0)
     in_step = threading.Event()
+    landed = threading.Event()
+    waits = []
 
     def hook():                      # blocks each superstep (lock held)
         in_step.set()
         gate.acquire()
 
     sched, qclass = _fake_scheduler(step_hook=hook)
+    pump = sched.pump
+
+    def pump_then_wait():
+        # drain() calls pump() with the lock released between calls:
+        # hold the drainer here until the raced submit has landed, so
+        # the submitter is not left to win the lock back from a drainer
+        # that re-takes it at once (threading.Lock is not fair)
+        n = pump()
+        if not waits:
+            waits.append(landed.wait(10))
+        return n
+
+    sched.pump = pump_then_wait
     fut1 = _submit_fake(sched, qclass, depth=6)
     order = []
 
@@ -337,6 +353,7 @@ def test_drain_keeps_admission_window_open():
     def submitter():
         got["fut2"] = _submit_fake(sched, qclass, depth=2)
         order.append("submit")
+        landed.set()
 
     s = threading.Thread(target=submitter)
     s.start()
@@ -352,6 +369,7 @@ def test_drain_keeps_admission_window_open():
     while t.is_alive():              # let the drain finish everything
         gate.release()
         t.join(0.01)
+    assert waits == [True], "submit never landed between supersteps"
     assert order and order[0] == "submit", order
     fut2 = got["fut2"]
     assert fut1.done() and fut2.done()

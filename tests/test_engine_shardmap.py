@@ -56,7 +56,7 @@ assert np.array_equal(comb["state"]["label"], uni["state"]["label"])
 assert comb["exchange_words"] < uni["exchange_words"], (
     comb["exchange_words"], uni["exchange_words"])
 
-# SSSP carry through the ring schedule
+# SSSP (distances, then the finalize's parents) through every schedule
 gw = G.uniform(200, 5.0, seed=4, weighted=True).symmetrized()
 pgw = PT.partition_graph(gw, 8, method="round_robin", pad_multiple=16)
 refs = Engine(ALG.sssp(0), pgw, mode="gravfm", backend="ref").run()
@@ -152,7 +152,7 @@ from repro.core.engine_shardmap import ShardEngine
 from repro.launch.mesh import auto_mesh
 
 mesh = auto_mesh((8,), ("graph",))
-# weighted so SSSP exercises the lexicographic (dist, parent) carry
+# weighted so SSSP's distances exercise each schedule's min fold
 # through the windowed pipeline's per-window merge
 gw = G.uniform(300, 6.0, seed=3, weighted=True).symmetrized()
 pg = PT.partition_graph(gw, 8, method="greedy", pad_multiple=16)

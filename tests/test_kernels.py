@@ -126,25 +126,3 @@ def test_layout_invariants(n_edges, n_segments, tile_e, tile_r, seed):
     pad[lanes] = False
     assert (lo.rel[pad] == tile_r).all()
     assert (lo.rel[lanes] == seg - (seg // tile_r) * tile_r).all()
-
-
-def test_carry_combine_matches_lexicographic():
-    """(key, carry) combine == lexicographic (min key, then min carry)."""
-    rng = np.random.default_rng(0)
-    n, s = 400, 37
-    seg = _random_sorted_segments(rng, n, s)
-    keys = rng.integers(0, 10, size=n).astype(np.float32)
-    carry = rng.integers(0, 1000, size=n).astype(np.int32)
-    acc, car = kref.segment_combine_carry(
-        jnp.asarray(keys), jnp.asarray(carry),
-        jnp.asarray(seg.astype(np.int32)), s, "min",
-        np.iinfo(np.int32).max)
-    acc, car = np.asarray(acc), np.asarray(car)
-    for b in range(s):
-        m = seg == b
-        if not m.any():
-            assert np.isinf(acc[b])
-            continue
-        kmin = keys[m].min()
-        assert acc[b] == kmin
-        assert car[b] == carry[m][keys[m] == kmin].min()

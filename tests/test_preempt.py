@@ -90,7 +90,8 @@ def test_park_restore_bit_identity(deep_graph, mode):
 
 
 def test_park_restore_sssp_carry(deep_graph):
-    """The argmin-carry (SSSP parent pointer) state survives a park."""
+    """SSSP state (distances and the superstep each last fell, from
+    which the retirement fetch finalizes parents) survives a park."""
     g = G.uniform(200, 6.0, seed=5, weighted=True).symmetrized()
     pg = PT.partition_graph(g, 4, method="greedy", pad_multiple=16)
     eng = Engine(ALG.sssp(), pg, mode="gravfm", backend="ref")
@@ -247,6 +248,21 @@ def test_threaded_preempt_while_retiring():
         gate.acquire()
 
     sched, qclass = _fake_scheduler(slots=1, stats=stats, step_hook=hook)
+    landed = threading.Event()
+    waits = []
+    pump = sched.pump
+
+    def pump_then_wait():
+        # drain() calls pump() with the lock released between calls:
+        # hold the drainer here until the raced submit has landed, so
+        # the submitter is not left to win the lock back from a drainer
+        # that re-takes it at once (threading.Lock is not fair)
+        n = pump()
+        if not waits:
+            waits.append(landed.wait(10))
+        return n
+
+    sched.pump = pump_then_wait
     futA = _submit_fake(sched, qclass, depth=10)
     order = []
     futA.add_done_callback(lambda f: order.append("A"))
@@ -260,6 +276,7 @@ def test_threaded_preempt_while_retiring():
         got["B"] = _submit_fake(sched, qclass, depth=2, deadline_ms=10,
                                 priority=1)
         got["B"].add_done_callback(lambda f: order.append("B"))
+        landed.set()
 
     s = threading.Thread(target=submitter)
     s.start()
@@ -271,6 +288,7 @@ def test_threaded_preempt_while_retiring():
     t.join(10)
     assert not t.is_alive(), "drain never finished"
     s.join(10)
+    assert waits == [True], "submit never landed between supersteps"
     futB = got["B"]
     assert futB.result(timeout=0).supersteps == 2
     # A RESUMED from its parked superstep: total superstep count intact
