@@ -31,7 +31,7 @@ import contextlib
 import dataclasses
 import functools
 import time
-from typing import Any, Callable, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -105,8 +105,12 @@ class EngineResult:
     messages: int                  # traversed edges (paper's TEPS numerator)
     comm: Dict[str, float]         # measured network words by scheme
     raw_state: Any = None          # sharded (P, Vm) state pytree
+    # messages delivered to each shard (P,), summing to ``messages``;
+    # None where the engine does not count them apart (``Engine``)
+    shard_messages: Optional[Tuple[int, ...]] = None
 
-    _FIELDS = ("state", "supersteps", "messages", "comm", "raw_state")
+    _FIELDS = ("state", "supersteps", "messages", "comm", "raw_state",
+               "shard_messages")
 
     def __getitem__(self, key):
         """Dict-style access (``res["state"]``, ``res["exchange_words"]``)

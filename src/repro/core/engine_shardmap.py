@@ -114,10 +114,11 @@ def _exchange_op(op):
     return f
 
 
-# the collectives every exchange runs on, each under gravfm.exchange
+# every collective of the shard programs, each under gravfm.exchange:
+# the exchanges themselves, the termination votes and the totals
 xc = types.SimpleNamespace(**{
     name: _exchange_op(getattr(jax.lax, name))
-    for name in ("all_gather", "all_to_all", "ppermute", "pmax")})
+    for name in ("all_gather", "all_to_all", "ppermute", "pmax", "psum")})
 
 
 def _count(mask):
@@ -135,7 +136,7 @@ def _counters(stats):
 
 def _psum_counters(stats):
     """:func:`_counters` summed over the shards (inside shard_map)."""
-    return {k: jax.lax.psum(v, AXIS) for k, v in _counters(stats).items()}
+    return {k: xc.psum(v, AXIS) for k, v in _counters(stats).items()}
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
@@ -305,41 +306,44 @@ def _build_combined_layouts(pg: PartitionedGraph, tile_e: int, tile_r: int):
             R, n_tiles, n_windows)
 
 
-def build_shard_data(pg: PartitionedGraph, *, tile_e: int = 512,
-                     tile_r: int = 256) -> tuple:
-    """(ShardData of numpy arrays, ShardMeta)."""
+def build_shard_data(pg: PartitionedGraph, *, exchange: str = "",
+                     tile_e: int = 512, tile_r: int = 256) -> tuple:
+    """(ShardData of numpy arrays, ShardMeta). Every exchange gets the
+    vertex arrays and the CSC lanes (a kernel's finalize reads them
+    whatever the exchange); ``exchange`` names the one layout beyond
+    them to build (ring buckets, unicast pair blocks or the combined
+    layout), and leaves the other fields None. "" builds all."""
     P, Vm = pg.num_parts, pg.v_max
     lanes, n_tiles, n_windows = _build_shard_layouts(pg, tile_e, tile_r)
-    comb, comb_max, comb_tiles, comb_windows = _build_combined_layouts(
-        pg, tile_e, tile_r)
+    fields = {k: np.ascontiguousarray(v) for k, v in lanes.items()}
+    comb_max = comb_tiles = comb_windows = 0
+    if exchange in ("", "combined"):
+        comb, comb_max, comb_tiles, comb_windows = _build_combined_layouts(
+            pg, tile_e, tile_r)
+        fields.update({k: np.ascontiguousarray(v) for k, v in comb.items()})
+    if exchange in ("", "ring"):
+        # ring buckets: shard p's in-edges grouped by source shard q =
+        # transpose of the pair (source-side) layout. src_local is local
+        # to q.
+        fields.update({f"rb_{k}": np.ascontiguousarray(
+            getattr(pg, f"pair_{k}").swapaxes(0, 1)) for k in (
+                "src_local", "src_gid", "src_outdeg", "w", "dst_local",
+                "valid")})
+    if exchange in ("", "unicast"):
+        fields.update(
+            pair_src_local=pg.pair_src_local, pair_src_gid=pg.pair_src_gid,
+            pair_src_outdeg=pg.pair_src_outdeg, pair_w=pg.pair_w,
+            pair_valid=pg.pair_valid,
+            recv_dst_local=pg.pair_dst_local.swapaxes(0, 1))
 
     flt = pg.nbr_filter.copy()
     flt[np.arange(pg.num_vertices), pg.part_of] = False
     flt_cnt = np.zeros((P, Vm), np.int32)
     flt_cnt[pg.part_of, pg.local_of] = flt.sum(axis=1).astype(np.int32)
 
-    # ring buckets: shard p's in-edges grouped by source shard q =
-    # transpose of the pair (source-side) layout. src_local is local to q.
-    rb = dict(
-        rb_src_local=pg.pair_src_local.swapaxes(0, 1),
-        rb_src_gid=pg.pair_src_gid.swapaxes(0, 1),
-        rb_src_outdeg=pg.pair_src_outdeg.swapaxes(0, 1),
-        rb_w=pg.pair_w.swapaxes(0, 1),
-        rb_dst_local=pg.pair_dst_local.swapaxes(0, 1),
-        rb_valid=pg.pair_valid.swapaxes(0, 1),
-    )
-
-    data = ShardData(
-        vert_gid=pg.vert_gid, vert_valid=pg.vert_valid, out_deg=pg.out_deg,
-        flt_cnt=flt_cnt,
-        **{k: np.ascontiguousarray(v) for k, v in lanes.items()},
-        **{k: np.ascontiguousarray(v) for k, v in rb.items()},
-        pair_src_local=pg.pair_src_local, pair_src_gid=pg.pair_src_gid,
-        pair_src_outdeg=pg.pair_src_outdeg, pair_w=pg.pair_w,
-        pair_valid=pg.pair_valid,
-        recv_dst_local=pg.pair_dst_local.swapaxes(0, 1),
-        **{k: np.ascontiguousarray(v) for k, v in comb.items()},
-    )
+    fields.update(vert_gid=pg.vert_gid, vert_valid=pg.vert_valid,
+                  out_deg=pg.out_deg, flt_cnt=flt_cnt)
+    data = ShardData(**{**dict.fromkeys(ShardData._fields), **fields})
     # frontier capacity buckets: powers of two up to Vm
     caps = []
     c = max(64, Vm // 16)
@@ -359,9 +363,11 @@ def build_shard_data(pg: PartitionedGraph, *, tile_e: int = 512,
 
 def abstract_shard_data(meta: ShardMeta, mesh=None,
                         exchange: str = "allgather") -> ShardData:
-    """ShapeDtypeStruct stand-ins for the dry-run (no allocation). Fields
-    unused by the chosen exchange are None (pruned from the input
-    signature, so argument bytes reflect what that architecture loads)."""
+    """ShapeDtypeStruct stand-ins for the dry-run (no allocation), shaped
+    as :func:`build_shard_data` builds for ``exchange``: the vertex
+    arrays, the CSC lanes and that exchange's own layout; the other
+    fields are None (pruned from the input signature, so argument bytes
+    reflect what that architecture loads)."""
     P, Vm, E2 = meta.P, meta.v_max, meta.e_pair_max
     Lf = meta.n_tiles * meta.tile_e
     CL = meta.comb_tiles * meta.tile_e
@@ -370,23 +376,21 @@ def abstract_shard_data(meta: ShardMeta, mesh=None,
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt)
 
-    none6 = (None,) * 6
-    csc = exchange in ("allgather", "frontier")
     ring = exchange == "ring"
     uni = exchange == "unicast"
     comb = exchange == "combined"
     return ShardData(
         vert_gid=sds((P, Vm), i32), vert_valid=sds((P, Vm), b),
         out_deg=sds((P, Vm), i32), flt_cnt=sds((P, Vm), i32),
-        wid=sds((P, meta.n_tiles), i32) if csc else None,
-        rel=sds((P, Lf), i32) if csc else None,
-        window_written=sds((P, meta.n_windows), b) if csc else None,
-        src_slot=sds((P, Lf), i32) if csc else None,
-        src_gid=sds((P, Lf), i32) if csc else None,
-        src_outdeg=sds((P, Lf), i32) if csc else None,
-        w=sds((P, Lf), f32) if csc else None,
-        lane_valid=sds((P, Lf), b) if csc else None,
-        seg=sds((P, Lf), i32) if csc else None,
+        wid=sds((P, meta.n_tiles), i32),
+        rel=sds((P, Lf), i32),
+        window_written=sds((P, meta.n_windows), b),
+        src_slot=sds((P, Lf), i32),
+        src_gid=sds((P, Lf), i32),
+        src_outdeg=sds((P, Lf), i32),
+        w=sds((P, Lf), f32),
+        lane_valid=sds((P, Lf), b),
+        seg=sds((P, Lf), i32),
         rb_src_local=sds((P, P, E2), i32) if ring else None,
         rb_src_gid=sds((P, P, E2), i32) if ring else None,
         rb_src_outdeg=sds((P, P, E2), i32) if ring else None,
@@ -431,11 +435,9 @@ class ShardEngine:
         if isinstance(pg_or_meta, PartitionedGraph):
             self.pg = pg_or_meta
             np_data, self.meta = build_shard_data(
-                pg_or_meta, tile_e=tile_e, tile_r=tile_r)
+                pg_or_meta, exchange=exchange, tile_e=tile_e, tile_r=tile_r)
             self.params.setdefault("num_vertices", pg_or_meta.num_vertices)
-            sharding = NamedSharding(mesh, P(AXIS))
-            self._data = jax.tree.map(
-                lambda a: jax.device_put(jnp.asarray(a), sharding), np_data)
+            self._data = self._put(np_data)
         else:
             self.pg = None
             self.meta = pg_or_meta
@@ -495,7 +497,7 @@ class ShardEngine:
                     "words": stats["words"] + aux["words"]}
 
         def global_any(b):
-            return jax.lax.pmax(b.astype(jnp.int32), AXIS) > 0
+            return xc.pmax(b.astype(jnp.int32), AXIS) > 0
 
         return SuperstepProgram(self.kernel, deliver,
                                 init_stats=init_stats,
@@ -557,8 +559,7 @@ class ShardEngine:
         m = self.meta
 
         def src_of(key):
-            with jax.named_scope("gravfm.exchange"):
-                g = xc.all_gather(state[key], AXIS)          # (P, Q, Vm)
+            g = xc.all_gather(state[key], AXIS)              # (P, Q, Vm)
             full = jnp.swapaxes(g, 0, 1).reshape(g.shape[1], -1)
             return jax.vmap(lambda x: jnp.take(x, d.src_slot))(full)
 
@@ -566,7 +567,7 @@ class ShardEngine:
             return self._local_combine(vals, d, combiner)
 
         def any_of(pred):
-            return jax.lax.pmax(jnp.any(pred).astype(jnp.int32), AXIS) > 0
+            return xc.pmax(jnp.any(pred).astype(jnp.int32), AXIS) > 0
 
         return LaneView(src_of=src_of, seg=d.seg, combine=combine,
                         any_of=any_of, vshape=(m.v_max,), w=d.w,
@@ -1006,12 +1007,11 @@ class ShardEngine:
             # shard_map blocks keep a size-1 leading (sharded) axis
             d = jax.tree.map(lambda a: a[0], d)
             c = prog.finalize_one(d, prog.while_run(d, cap, self.params, qkw))
-            total_msgs = jax.lax.psum(c.stats["messages"], AXIS)
-            total_words = jax.lax.psum(c.stats["words"], AXIS)
-            # re-add shard axis
+            total_words = xc.psum(c.stats["words"], AXIS)
+            # re-add shard axis: the state and this shard's messages
             state = jax.tree.map(lambda a: a[None], c.state)
-            return (state, c.superstep, total_msgs, total_words,
-                    _psum_counters(c.stats))
+            return (state, c.superstep, c.stats["messages"][None],
+                    total_words, _psum_counters(c.stats))
 
         in_specs = jax.tree.map(lambda _: P(AXIS), self._data,
                                 is_leaf=lambda x: x is None)
@@ -1021,7 +1021,7 @@ class ShardEngine:
             named(shard_fn, self._program_name("run", overlap)),
             mesh=self.mesh,
             in_specs=(in_specs, qspec),
-            out_specs=(state_spec, P(), P(), P(), P()))
+            out_specs=(state_spec, P(), P(AXIS), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
@@ -1049,13 +1049,13 @@ class ShardEngine:
                 # per-query distributed termination bit (§4.3, per lane)
                 with jax.named_scope("gravfm.cond"):
                     loc = jnp.any(c.active, axis=-1).astype(jnp.int32)
-                    return jax.lax.pmax(loc, AXIS) > 0       # (B,)
+                    return xc.pmax(loc, AXIS) > 0            # (B,)
 
             def cond(st):
                 s, c = st
                 with jax.named_scope("gravfm.cond"):
                     any_local = jnp.any(c.active).astype(jnp.int32)
-                    return (jax.lax.pmax(any_local, AXIS) > 0) & (s < cap)
+                    return (xc.pmax(any_local, AXIS) > 0) & (s < cap)
 
             def body(st):
                 s, c = st
@@ -1069,13 +1069,12 @@ class ShardEngine:
                 _, carry = jax.lax.while_loop(
                     cond, body, (jnp.int32(0), carry))
             carry = prog.finalize(d, carry)
-            total_msgs = jax.lax.psum(carry.stats["messages"], AXIS)  # (B,)
-            total_words = jax.lax.psum(
-                jnp.sum(carry.stats["words"]), AXIS)
-            # re-add shard axis leading so out spec P(AXIS) shards it
-            state = jax.tree.map(lambda a: a[None], carry.state)  # (1, B, ·)
-            return (state, carry.superstep, total_msgs, total_words,
-                    _psum_counters(carry.stats))
+            total_words = xc.psum(jnp.sum(carry.stats["words"]), AXIS)
+            # re-add shard axis leading so out spec P(AXIS) shards it:
+            # the state (1, B, ·) and this shard's messages (1, B)
+            state = jax.tree.map(lambda a: a[None], carry.state)
+            return (state, carry.superstep, carry.stats["messages"][None],
+                    total_words, _psum_counters(carry.stats))
 
         in_specs = jax.tree.map(lambda _: P(AXIS), self._data,
                                 is_leaf=lambda x: x is None)
@@ -1084,7 +1083,7 @@ class ShardEngine:
             named(shard_fn, self._program_name(f"batch{batch}", overlap)),
             mesh=self.mesh,
             in_specs=(in_specs, qspec),
-            out_specs=(P(AXIS), P(), P(), P(), P()))
+            out_specs=(P(AXIS), P(), P(AXIS), P(), P()))
         fn = jax.jit(fn)
         self._run_cache[ck] = fn
         return fn
@@ -1135,14 +1134,16 @@ class ShardEngine:
             state_np, s, msgs, words, ctr = jax.tree.map(np.asarray, out)
         from .engine import EngineResult, collect
         with span(self.trace, "collect"):
+            shard_msgs = tuple(int(x) for x in msgs)      # (P,)
             return EngineResult(
                 state=collect(self.pg, state_np) if self.pg else state_np,
                 supersteps=int(s[0] if np.ndim(s) else s),
-                messages=int(msgs.reshape(-1)[0]),
+                messages=sum(shard_msgs),
                 comm=self._result_comm(
                     float(words.reshape(-1)[0]),
                     {k: float(v.reshape(-1)[0]) for k, v in ctr.items()}),
                 raw_state=state_np,
+                shard_messages=shard_msgs,
             )
 
     def run_batch(self, max_supersteps: Optional[int] = None,
@@ -1172,7 +1173,7 @@ class ShardEngine:
         from .engine import EngineResult, collect
         with span(self.trace, "collect"):
             sq = sq.reshape(-1, sq.shape[-1])[0]
-            msgs = msgs.reshape(-1, msgs.shape[-1])[0]
+            msgs = msgs.astype(np.int64)                  # (P, B)
             words = float(words.reshape(-1)[0])
             ctr = {k: v.reshape(-1, v.shape[-1])[0] for k, v in ctr.items()}
             out = []
@@ -1181,10 +1182,11 @@ class ShardEngine:
                 out.append(EngineResult(
                     state=collect(self.pg, state_q) if self.pg else state_q,
                     supersteps=int(sq[q]),
-                    messages=int(msgs[q]),
+                    messages=int(msgs[:, q].sum()),
                     comm=self._result_comm(
                         words, {k: float(v[q]) for k, v in ctr.items()}),
                     raw_state=state_q,
+                    shard_messages=tuple(int(x) for x in msgs[:, q]),
                 ))
             return out
 
@@ -1194,6 +1196,12 @@ class ShardEngine:
         if self._data is None:
             return 0
         return int(sum(a.nbytes for a in jax.tree.leaves(self._data)))
+
+    def _put(self, host: ShardData) -> ShardData:
+        """Host arrays (leading shard axis) to their mesh sharding: each
+        device receives its own shard straight from the host, so none
+        ever holds a whole array."""
+        return jax.device_put(host, NamedSharding(self.mesh, P(AXIS)))
 
     # ---------------- residency tier (see Engine.offload/upload) -------
     @property
@@ -1218,9 +1226,7 @@ class ShardEngine:
         if self._data is None or self._device_resident:
             return 0.0
         t0 = time.perf_counter()
-        sharding = NamedSharding(self.mesh, P(AXIS))
-        data = jax.tree.map(
-            lambda a: jax.device_put(jnp.asarray(a), sharding), self._data)
+        data = self._put(self._data)
         jax.block_until_ready(data)
         self._data = data
         self._device_resident = True
@@ -1261,6 +1267,8 @@ class ShardEngine:
             comm=self._result_comm(float(stats["words"]), {
                 k: float(v) for k, v in _counters(stats).items()}),
             raw_state=state_q,
+            shard_messages=tuple(
+                int(x) for x in carry_host.stats["messages"][:, lane]),
         )
 
     # ---------------- dry-run hooks ------------------------------------

@@ -743,7 +743,8 @@ class ContinuousScheduler:
                     messages=res.messages, latency_ms=latency_ms,
                     class_key=class_key(qclass),
                     wire_words=float((getattr(res, "comm", None) or {})
-                                     .get("wire_words", 0.0)))
+                                     .get("wire_words", 0.0)),
+                    shard_messages=getattr(res, "shard_messages", None))
                 self.stats.record_query_depth(
                     class_key(qclass), res.supersteps,
                     bucket=getattr(meta, "depth_bucket", None))

@@ -500,6 +500,7 @@ class GraphQueryService:
             messages=res.messages,
             comm=dict(res.comm),
             raw_state=jax.tree.map(np.array, res.raw_state),
+            shard_messages=res.shard_messages,
         )
 
     def _lookup_result(self, req: QueryRequest,
@@ -807,6 +808,8 @@ class GraphQueryService:
                 f.set_result(res)
             traces_after = self.plans.sync_trace_counters()
             compiled = traces_after != traces_before
+            shard_msgs = [r.shard_messages for r in results
+                          if r.shard_messages is not None]
             self.stats.record_batch(
                 n_queries=n, n_pad=max(0, bucket - n) if bucket > 1 else 0,
                 # a traced dispatch's wall is compile-dominated: account it
@@ -819,7 +822,9 @@ class GraphQueryService:
                 latencies_ms=[(now - r.arrival_s) * 1e3 for r in reqs],
                 class_key=ck,
                 wire_words=sum(float(r.comm.get("wire_words", 0.0))
-                               for r in results))
+                               for r in results),
+                shard_messages=(np.sum(shard_msgs, axis=0).tolist()
+                                if shard_msgs else None))
             if compiled:
                 self.stats.record_compile(wall)
             # feed the admission-control cost model + the result cache;

@@ -57,6 +57,10 @@ class ServiceStats:
     deadline_misses: int = 0        # queries retired past their deadline
     supersteps_total: int = 0
     messages_total: int = 0         # traversed edges (TEPS numerator)
+    # messages delivered to each shard, summing to the part of
+    # messages_total that a shard engine served; empty until one has
+    shard_messages_total: List[int] = dataclasses.field(
+        default_factory=list)
     wire_words_total: float = 0.0   # exchange words moved across shards
     busy_time_s: float = 0.0        # wall time spent EXECUTING dispatches
     compile_time_s: float = 0.0     # wall time spent tracing/compiling
@@ -125,8 +129,10 @@ class ServiceStats:
                      messages: int, supersteps: int,
                      latencies_ms: List[float],
                      class_key: Optional[str] = None,
-                     wire_words: float = 0.0) -> None:
+                     wire_words: float = 0.0,
+                     shard_messages: Optional[List[int]] = None) -> None:
         with self._lock:
+            self._add_shard_messages(shard_messages)
             self.batches_dispatched += 1
             self.queries_completed += n_queries
             self.batch_pad_queries += n_pad
@@ -141,6 +147,15 @@ class ServiceStats:
                 acc["busy_s"] += wall_s
                 acc["completed"] += n_queries
                 acc["wire_words"] += wire_words
+
+    def _add_shard_messages(self, counts) -> None:
+        """Add per-shard message counts (caller holds the lock)."""
+        if counts is None:
+            return
+        tot = self.shard_messages_total
+        tot.extend([0] * (len(counts) - len(tot)))
+        for p, n in enumerate(counts):
+            tot[p] += int(n)
 
     def record_cache(self, hit: bool) -> None:
         with self._lock:
@@ -312,11 +327,13 @@ class ServiceStats:
 
     def record_retire(self, messages: int, latency_ms: float,
                       class_key: Optional[str] = None,
-                      wire_words: float = 0.0) -> None:
+                      wire_words: float = 0.0,
+                      shard_messages: Optional[List[int]] = None) -> None:
         """One query retired mid-flight by the continuous scheduler.
         (Device supersteps are counted per pump via record_pump_step,
         not per query — W lanes share each superstep.)"""
         with self._lock:
+            self._add_shard_messages(shard_messages)
             self.queries_completed += 1
             self.messages_total += messages
             self.wire_words_total += wire_words
@@ -412,6 +429,7 @@ class ServiceStats:
                     if self._depth_err_ewma else 0.0),
                 "supersteps_total": self.supersteps_total,
                 "messages_total": self.messages_total,
+                "shard_messages_total": list(self.shard_messages_total),
                 "wire_words_total": self.wire_words_total,
                 "busy_time_s": self.busy_time_s,
                 "compile_time_s": self.compile_time_s,

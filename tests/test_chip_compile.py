@@ -162,13 +162,9 @@ def test_shard_sssp_finalize_compiles_for_v5e_2x2(topo, small_pg, exchange):
     eng = ShardEngine(ALG.sssp(), meta, mesh=mesh, exchange=exchange,
                       backend="pallas")
     sharded = NamedSharding(mesh, PartitionSpec("graph"))
-    data = abstract_shard_data(meta, exchange=exchange)
-    # the pass reads the CSC lanes, which this exchange's loop does not
-    csc = abstract_shard_data(meta, exchange="allgather")
-    data = data._replace(**{f: c for f, c, x in zip(data._fields, csc, data)
-                            if x is None and c is not None})
+    # every exchange's data holds the CSC lanes the pass reads
     eng._data = jax.tree.map(lambda s: _sds(s.shape, s.dtype, sharded),
-                             data)
+                             abstract_shard_data(meta, exchange=exchange))
     fn = eng._make_run_batch(100, ("root",), 8)
     text = fn.lower(eng._data,
                     {"root": jax.ShapeDtypeStruct((8,), jnp.int32)}
@@ -178,3 +174,37 @@ def test_shard_sssp_finalize_compiles_for_v5e_2x2(topo, small_pg, exchange):
     assert sorted(line.split("= ")[1][:3] for line in gathers) == [
         "f32", "f32", "s32"], gathers
     assert " conditional(" in text
+
+
+# the shard layout of the Graph500 scale-20 graph of the g500-s20-mesh
+# configuration, greedy-partitioned over 4 shards (build_shard_data with
+# exchange="allgather"): 1,048,576 vertices, 31,399,038 directed edges,
+# at most 7,849,958 in-edges a shard
+S20_MESH = dict(P=4, v_max=264704, e_pair_max=1963968, n_tiles=15848,
+                n_windows=1035, tile_e=TILE_E, tile_r=TILE_R,
+                num_vertices=1 << 20,
+                frontier_capacities=(16544, 66176, 264704))
+V5E_HBM = 15.75e9      # what the chip's compiler lets a program use
+
+
+@pytest.mark.parametrize("kernel", ["bfs", "sssp"])
+def test_served_mesh_batch_fits_v5e_2x2_at_scale_20(topo, kernel):
+    """The batch-32 program g500-s20.mix-mesh serves (allgather, the
+    ref backend), compiled for four described chips at the real shard
+    sizes: each chip's share fits its HBM with the graph's own arrays
+    beside it. One chip cannot hold the graph's BFS batch of 32."""
+    from repro.core.engine_shardmap import ShardMeta
+    mesh = Mesh(np.array(topo.devices), ("graph",))
+    meta = ShardMeta(**S20_MESH)
+    eng = ShardEngine(getattr(ALG, kernel)(), meta, mesh=mesh,
+                      exchange="allgather", backend="ref")
+    sharded = NamedSharding(mesh, PartitionSpec("graph"))
+    eng._data = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, sharded),
+        abstract_shard_data(meta, exchange="allgather"))
+    compiled = eng.lower(32).compile()
+    m = compiled.memory_analysis()
+    per_chip = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes)
+    assert 0 < per_chip < V5E_HBM, per_chip
+    assert "all-gather" in compiled.as_text()

@@ -217,3 +217,62 @@ def test_shardmap_overlap_multidevice():
                           capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "SHARDMAP-OVERLAP-OK" in proc.stdout
+
+
+_UPLOAD_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, {src!r})
+import jax, numpy as np
+from repro.core import graph as G, partition as PT, algorithms as ALG
+from repro.core.engine_shardmap import ShardEngine
+from repro.launch.mesh import make_serving_mesh
+
+def device_bytes():
+    out = dict.fromkeys(jax.devices(), 0)
+    for a in jax.live_arrays():
+        shard = np.prod(a.sharding.shard_shape(a.shape), dtype=int)
+        for d in a.sharding.device_set:
+            out[d] += int(shard) * a.dtype.itemsize
+    return [out[d] for d in jax.devices()]
+
+samples = []
+put = jax.device_put
+
+def watched_put(*args, **kwargs):
+    # what each device holds as an upload starts and once it is done
+    samples.append(device_bytes())
+    out = put(*args, **kwargs)
+    jax.block_until_ready(out)
+    samples.append(device_bytes())
+    return out
+
+jax.device_put = watched_put
+g = G.rmat(9, 8, seed=2, weighted=True).symmetrized()
+pg = PT.partition_graph(g, 4)
+eng = ShardEngine(ALG.sssp(), pg, mesh=make_serving_mesh(4),
+                  exchange="allgather", backend="ref")
+after_build = device_bytes()
+assert eng.offload() > 0 and device_bytes() == [0, 0, 0, 0]
+eng.upload()
+assert device_bytes() == after_build, (device_bytes(), after_build)
+assert after_build[0] > 0 and len(samples) >= 4
+for b in samples:
+    assert all(b[0] <= x for x in b[1:]), b
+print("SHARDMAP-UPLOAD-OK", after_build)
+"""
+
+
+@pytest.mark.slow
+def test_shard_upload_never_stages_on_device_0():
+    """ShardEngine's construction and its re-upload after ``offload``
+    send each shard straight from the host to its device: device 0
+    never holds more bytes than any other device, before, during or
+    after an upload."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    script = _UPLOAD_SCRIPT.format(src=os.path.abspath(src))
+    proc = subprocess.run([sys.executable, "-c", script],
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "SHARDMAP-UPLOAD-OK" in proc.stdout
