@@ -39,6 +39,7 @@ import numpy as np
 
 from ..kernels import ops as kops
 from ..kernels import ref as kref
+from ..kernels.degree_class import ClassSegments, build_classes
 from ..kernels.edge_gather import segment_combine_windows
 from .gas import GasKernel
 from .partition import PartitionedGraph
@@ -83,6 +84,8 @@ class _GravfmData(NamedTuple):
     wid: Optional[jnp.ndarray] = None      # (n_tiles,) int32
     rel: Optional[jnp.ndarray] = None      # (L,) int32
     written: Optional[jnp.ndarray] = None  # (n_windows,) bool
+    # in-degree-class layout (backend="ref"; None for "pallas")
+    inv: Optional[jnp.ndarray] = None      # (S,) int32 segment -> class row
 
 
 class _GravfData(NamedTuple):
@@ -160,6 +163,10 @@ class Engine:
         flt_cnt = np.zeros((P, Vm), np.int32)
         flt_cnt[pg.part_of, pg.local_of] = flt_cnt_g
 
+        # the in-degree-class layout's padded lanes over its edges, and
+        # its class count (mode "gravfm" on backend "ref"; else None)
+        self.combine_pad_share: Optional[float] = None
+        self.combine_classes: Optional[int] = None
         if mode == "gravfm":
             self._data = self._build_gravfm(flt_cnt, tile_e, tile_r)
         else:
@@ -192,23 +199,18 @@ class Engine:
         if self.backend == "pallas":
             layout = kops.build_layout(seg_flat, S, tile_e=tile_e,
                                        tile_r=tile_r)
-            self._layout = layout
-            place = layout.place
-            src_slot = place(pg.in_src_slot.reshape(-1), 0)
-            src_gid = place(pg.in_src_gid.reshape(-1), 0)
-            src_outdeg = place(pg.in_src_outdeg.reshape(-1), 1)
-            w = place(pg.in_w.reshape(-1), 0.0)
-            lane_valid = place(valid_flat, False) & layout.lane_valid
-            seg = place(seg_flat.astype(np.int32), S)
         else:
-            self._layout = None
-            src_slot = pg.in_src_slot.reshape(-1)
-            src_gid = pg.in_src_gid.reshape(-1)
-            src_outdeg = pg.in_src_outdeg.reshape(-1)
-            w = pg.in_w.reshape(-1)
-            lane_valid = valid_flat
-            seg = seg_flat.astype(np.int32)
-        self._num_segments = S
+            layout = build_classes(seg_flat, valid_flat, S)
+            self.combine_pad_share = layout.pad_share
+            self.combine_classes = len(layout.classes)
+        self._layout = layout
+        place = layout.place
+        src_slot = place(pg.in_src_slot.reshape(-1), 0)
+        src_gid = place(pg.in_src_gid.reshape(-1), 0)
+        src_outdeg = place(pg.in_src_outdeg.reshape(-1), 1)
+        w = place(pg.in_w.reshape(-1), 0.0)
+        lane_valid = place(valid_flat, False) & layout.lane_valid
+        seg = place(seg_flat.astype(np.int32), S)
         # src shard of each lane vs owning shard of its segment
         src_part = src_slot // Vm
         dst_part = seg // (Vm + 1)
@@ -224,11 +226,12 @@ class Engine:
             w=jnp.asarray(w),
             lane_valid=jnp.asarray(lane_valid),
             lane_remote=jnp.asarray(lane_remote),
-            seg=jnp.asarray(np.minimum(seg, S).astype(np.int32)),
-            **({} if self._layout is None else dict(
-                wid=jnp.asarray(self._layout.window_id),
-                rel=jnp.asarray(self._layout.rel),
-                written=jnp.asarray(self._layout.window_written))),
+            seg=jnp.asarray(seg),
+            **(dict(wid=jnp.asarray(layout.window_id),
+                    rel=jnp.asarray(layout.rel),
+                    written=jnp.asarray(layout.window_written))
+               if self.backend == "pallas" else
+               dict(inv=jnp.asarray(layout.inv))),
         )
 
     def _build_gravf(self, flt_cnt) -> _GravfData:
@@ -249,15 +252,18 @@ class Engine:
     # ------------------------------------------------------------------
     def _combine(self, data: _GravfmData, vals, combiner: str):  # analysis: traced
         """Segmented combine over the CSC lanes: the Pallas kernel over
-        the tile layout carried in ``data``, or the jnp oracle."""
+        the tile layout carried in ``data``, or one dense reduce per
+        in-degree class (its padding lanes set to the identity)."""
+        lo = self._layout
         if self.backend == "pallas":
-            lo = self._layout
             return segment_combine_windows(
                 data.wid, data.rel, vals, combiner=combiner,
                 tile_e=lo.tile_e, tile_r=lo.tile_r, n_windows=lo.n_windows,
                 window_written=data.written, num_segments=lo.num_segments)
-        return kref.segment_combine(vals, data.seg, self._num_segments,
-                                    combiner)
+        ident = kops.identity_for(combiner, vals.dtype)
+        return kref.segment_combine(jnp.where(data.lane_valid, vals, ident),
+                                    ClassSegments(data.inv, lo.classes),
+                                    lo.num_segments, combiner)
 
     def _deliver_gravfm(self, data: _GravfmData, payload, active):  # analysis: traced
         """Broadcast updates; receiver-side scatter + gather-combine."""

@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -159,6 +159,13 @@ class StepperPlan:
         return tuple(self.engine.kernel.query_params)
 
 
+def combine_layout(engine: Engine) -> Dict[str, Any]:
+    """An engine's in-degree-class layout: padded lanes over its edges,
+    and the number of classes."""
+    return {"combine_pad_share": engine.combine_pad_share,
+            "combine_classes": engine.combine_classes}
+
+
 class PlanCache:
     """Multi-level cache: partitioned graphs (via the GraphStore),
     device-resident engines, compiled plans, lane steppers.
@@ -259,7 +266,8 @@ class PlanCache:
             pg = self.graph(key.graph_id, key.num_shards, method,
                             version=key.version or None)
             with self.trace.span("engine_build", graph_id=key.graph_id,
-                                 version=key.version, kernel=key.kernel):
+                                 version=key.version,
+                                 kernel=key.kernel) as build:
                 if key.exchange:
                     from ..core.engine_shardmap import ShardEngine
                     from ..launch.mesh import make_serving_mesh
@@ -270,6 +278,8 @@ class PlanCache:
                 else:
                     eng = Engine(ALGORITHMS[key.kernel](), pg,
                                  mode=key.mode, backend=key.backend)
+                    if eng.combine_classes is not None:
+                        build.note(**combine_layout(eng))
             eng.trace = self.trace
             self._engines[ek] = eng
             # charge the TRUE engine-tier device bytes against the
@@ -406,6 +416,15 @@ class PlanCache:
         return total
 
     # ---------------- introspection -----------------------------------
+    def combine_layouts(self) -> List[Dict[str, Any]]:
+        """One entry per cached engine whose combine runs over an
+        in-degree-class layout: its graph, version and kernel, the
+        layout's padded-lane share and its class count."""
+        return [dict(graph_id=ek[0], version=ek[1], kernel=ek[2],
+                     **combine_layout(eng))
+                for ek, eng in list(self._engines.items())
+                if getattr(eng, "combine_classes", None) is not None]
+
     def describe(self) -> Dict[str, Any]:
         return {
             "graphs": sorted(

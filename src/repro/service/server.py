@@ -953,6 +953,7 @@ class GraphQueryService:
         for k, v in self.store.snapshot().items():
             snap[f"store_{k}"] = v
         snap["tenants"] = self.stats.tenant_snapshot()
+        snap["combine_layout"] = self.plans.combine_layouts()
         snap["trace_events"] = self.trace.emitted
         snap["trace_dropped"] = self.trace.dropped
         return snap

@@ -141,6 +141,10 @@ class Span:
         self._ann = jax.profiler.TraceAnnotation(SCOPE_PREFIX + kind)
         self._ann.__enter__()
 
+    def note(self, **fields) -> None:
+        """Add ``fields`` to the event the span records when it ends."""
+        self._fields.update(fields)
+
     def end(self, ts: Optional[float] = None) -> None:
         """Close the span at ``ts`` (default now); later calls do
         nothing. Call it on the thread that opened it."""
@@ -163,6 +167,9 @@ class Span:
 
 class _NullSpan:
     """What a disabled bus hands out: every call is a no-op."""
+
+    def note(self, **fields) -> None:
+        pass
 
     def end(self, ts: Optional[float] = None) -> None:
         pass

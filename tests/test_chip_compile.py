@@ -6,6 +6,8 @@ so the served programs are compiled for it here, with no chip attached.
 The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs these tests loads the TPU compiler library.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -208,3 +210,51 @@ def test_served_mesh_batch_fits_v5e_2x2_at_scale_20(topo, kernel):
                 + m.temp_size_in_bytes)
     assert 0 < per_chip < V5E_HBM, per_chip
     assert "all-gather" in compiled.as_text()
+
+
+# the one-chip engine's in-degree classes of the Graph500 scale-18 graph
+# of the g500-s18 configuration, greedy-partitioned over the service's 4
+# shards (build_classes): 262,144 vertices, 7,609,366 directed edges in
+# 7,896,144 lanes, (class width, rows)
+S18_CLASSES = (
+    (1, 35360), (2, 19856), (3, 12976), (4, 10056), (5, 8536), (6, 7520),
+    (7, 6168), (8, 4557), (9, 3104), (10, 2064), (11, 1504), (12, 1400),
+    (13, 1504), (14, 1840), (15, 2232), (16, 2576), (18, 5896),
+    (20, 5576), (22, 4432), (24, 2936), (26, 1640), (28, 856), (30, 376),
+    (32, 109), (36, 80), (40, 86), (44, 368), (48, 1022), (52, 2136),
+    (56, 3458), (60, 3768), (64, 3423), (72, 3547), (80, 682), (88, 67),
+    (96, 3), (144, 10), (160, 311), (176, 2256), (192, 3887), (208, 1840),
+    (224, 256), (240, 8), (512, 46), (576, 2312), (640, 702), (1536, 2),
+    (1664, 621), (1792, 193), (4608, 153), (11264, 8), (12288, 10),
+    (26624, 1))
+S18 = dict(P=4, v_max=67584)
+
+
+@pytest.mark.parametrize("kernel", ["bfs", "sssp"])
+def test_served_batch_fits_v5e_at_scale_18(one_chip, small_pg, kernel):
+    """The batch-32 program g500-s18.mix serves (Engine, the ref
+    backend), compiled for one described chip at the real class layout:
+    it fits the chip's HBM with the graph's own arrays beside it, and its
+    combine holds no scatter."""
+    eng = Engine(getattr(ALG, kernel)(), small_pg, backend="ref")
+    P, Vm = S18["P"], S18["v_max"]
+    S, L = P * (Vm + 1), sum(w * n for w, n in S18_CLASSES)
+    eng._P, eng._Vm = P, Vm
+    eng._layout = dataclasses.replace(eng._layout, num_segments=S,
+                                      classes=S18_CLASSES)
+
+    def shape(field, a):
+        n = (P, Vm) if a.ndim == 2 else (S,) if field == "inv" else (L,)
+        return _sds(n, a.dtype, one_chip)
+
+    data = eng._data._replace(**{f: shape(f, a) for f, a
+                                 in eng._data._asdict().items()
+                                 if a is not None})
+    compiled = eng._make_batch_program(32).lower(
+        data, _sds((), jnp.int32, one_chip),
+        {"root": _sds((32,), jnp.int32, one_chip)}).compile()
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert 0 < total < V5E_HBM, total
+    assert " scatter(" not in compiled.as_text()

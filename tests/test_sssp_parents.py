@@ -7,8 +7,9 @@ gives the parents, which every engine path must reproduce bit for bit
 on graphs full of tied shortest paths: integer weights, zero weights,
 and weights below half an ulp of the distance they are added to. The
 parent graph must be a tree of neighbours that attain each distance,
-also for a query stopped by the superstep cap; and the loop itself must
-hold one scatter, as BFS's does, with the finalize outside it."""
+also for a query stopped by the superstep cap; and the one-chip
+program's loop must hold no scatter, as BFS's, with the finalize outside
+it."""
 import os
 import re
 import subprocess
@@ -415,15 +416,20 @@ def _scatters(comps, names):
             for n in re.findall(r"%?([\w.\-]+) = \S+ scatter\(", line)]
 
 
+def _ops(comps, names):
+    return {n for c in names for line in comps[c]
+            for n in re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", line)}
+
+
 def test_sssp_loop_holds_one_scatter_and_finalize_sits_outside():
-    """The SSSP batch program's while body holds as many scatter ops as
-    BFS's (one: the value pass's segment-min); the finalize's
-    segment-mins sit outside the loop, under ``gravfm.finalize``, and
-    its tie-break is a conditional, not a select over both branches."""
+    """The one-chip ``ref`` batch programs hold no scatter: the superstep
+    loop's combine, like the finalize's segment-mins, is one dense
+    reduce per in-degree class. SSSP's finalize sits outside the loop,
+    under ``gravfm.finalize``, and its tie-break is a conditional, not a
+    select over both branches."""
     from repro.service.trace import hlo_op_scopes
     g = tie_graph("integer", side=8)
     pg = PT.partition_graph(g, 2)
-    loop_scatters, outside = {}, {}
     for name in ("bfs", "sssp"):
         eng = Engine(ALG.ALGORITHMS[name](), pg, backend="ref")
         text = eng.lower_batch(4).compile().as_text()
@@ -435,15 +441,16 @@ def test_sssp_loop_holds_one_scatter_and_finalize_sits_outside():
                   for b in re.findall(r"while\(.*body=%?([\w.\-]+)", line)]
         assert len(bodies) == 1, name
         inner = _reachable(comps, bodies)
-        loop_scatters[name] = _scatters(comps, inner)
-        outside[name] = _scatters(comps, set(comps) - inner)
+        assert not _scatters(comps, comps), name
         _, scopes = hlo_op_scopes(text)
+        # the edge pass's one combine: a reduce per class
+        reduces = [n for c in inner for line in comps[c]
+                   for n in re.findall(r"%?([\w.\-]+) = \S+ reduce\(", line)
+                   if scopes.get(n) == "gravfm.deliver"]
+        assert len(reduces) == eng.combine_classes > 1, name
         finalize = {op for op, s in scopes.items() if s == "gravfm.finalize"}
         if name == "bfs":
-            assert not finalize and not outside[name]
+            assert not finalize
         else:
-            assert finalize
+            assert finalize and not finalize & _ops(comps, inner)
             assert " conditional(" in text
-    assert len(loop_scatters["bfs"]) == len(loop_scatters["sssp"]) == 1
-    # the strictly-shorter pass, then the fallback's two (t, then id)
-    assert len(outside["sssp"]) == 3
